@@ -10,6 +10,8 @@ import json
 import os
 import sys
 
+import jax
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from test_engine import _run_three_party_trace  # noqa: E402
@@ -20,7 +22,8 @@ OUT = os.path.join(os.path.dirname(__file__), "three_party_trace.json")
 def main():
     rows = _run_three_party_trace(rounds=20)
     with open(OUT, "w") as f:
-        json.dump({"celu": rows}, f, indent=1)
+        json.dump({"celu": rows, "jax_version": jax.__version__}, f,
+                  indent=1)
     print(f"wrote {OUT}: {len(rows) - 1} rounds")
     print("first:", rows[0])
     print("tail: ", rows[-1])

@@ -14,6 +14,8 @@ import json
 import os
 import sys
 
+import jax
+
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from test_engine import _run_trace  # noqa: E402
@@ -24,10 +26,11 @@ OUT = os.path.join(os.path.dirname(__file__), "two_party_trace.json")
 def main():
     trace = {proto: _run_trace(proto, via_shim=False, rounds=20)
              for proto in ("vanilla", "fedbcd", "celu")}
+    trace["jax_version"] = jax.__version__
     with open(OUT, "w") as f:
         json.dump(trace, f, indent=1)
-    print(f"wrote {OUT}: {len(trace)} protocols x {len(trace['celu']) - 1} "
-          f"rounds")
+    print(f"wrote {OUT}: {len(trace) - 1} protocols x "
+          f"{len(trace['celu']) - 1} rounds on jax {jax.__version__}")
     print("celu tail:", trace["celu"][-1])
 
 
