@@ -195,9 +195,9 @@ def _probe_fs_q8(g: Geometry):
     from ..kernels import ops
     return ops.fused_gather_weight_q8, (_i32(), _f32(g.B, g.F),
                                         _i8(g.W, g.B, g.F),
-                                        _f32(g.W, g.B),
+                                        _f32(g.W, g.B, 1),
                                         _i8(g.W, g.B, g.F),
-                                        _f32(g.W, g.B), 0.5)
+                                        _f32(g.W, g.B, 1), 0.5)
 
 
 def _probe_fs_q4(g: Geometry):
@@ -205,9 +205,9 @@ def _probe_fs_q4(g: Geometry):
     P = -(-g.F // 2)
     return ops.fused_gather_weight_q4, (_i32(), _f32(g.B, g.F),
                                         _u8(g.W, g.B, P),
-                                        _f32(g.W, g.B),
+                                        _f32(g.W, g.B, 1),
                                         _u8(g.W, g.B, P),
-                                        _f32(g.W, g.B), 0.5)
+                                        _f32(g.W, g.B, 1), 0.5)
 
 
 def _probe_ag_q8(g: Geometry):

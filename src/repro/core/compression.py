@@ -125,7 +125,7 @@ class StochasticQuantCodec:
             lo = (q & 0xF).astype(jnp.int8) - 8
             hi = (q >> 4).astype(jnp.int8) - 8
             q = jnp.stack([lo, hi], axis=-1).reshape(q.shape[0], -1)
-        x2d = q.astype(jnp.float32) * scale[:, None]
+        x2d = q.astype(jnp.float32) * scale      # (T, 1) row scales
         n = _nelem(like.shape)
         return x2d.ravel()[:n].reshape(like.shape).astype(like.dtype)
 

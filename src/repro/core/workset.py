@@ -44,7 +44,8 @@ the cut-statistic subtrees (the ``z``/``dz`` entry keys, ``QUANT_KEYS``):
     let the fused sample kernel gather + dequantize + weight in one VMEM
     pass without re-tiling.
   * ``"int4"`` — int4 codes (levels = ±7), nibble-packed two per byte
-    (:class:`Quant4Leaf`; the PR-2 wire codec's packing applied at rest).
+    (:class:`Quant4Leaf`; lane-grouped so the fused kernels unpack with
+    aligned slices, see :func:`pack_nibbles`).
     Same per-row fp32 scale, same SR quantizer at ``levels=7``; odd row
     widths pad one zero code before packing (the pad nibble decodes to an
     exact zero, so it contributes nothing to the cosine reductions).
@@ -95,8 +96,8 @@ class QuantLeaf:
     """int8-at-rest storage of one cached statistic leaf.
 
     ``q`` holds signed int8 codes of the leaf flattened to (B, F) rows
-    (table level: (W, B, F)), ``scale`` one fp32 absmax scale per row
-    ((B,) / (W, B)).  ``shape``/``dtype`` remember the original per-entry
+    (table level: (W, B, F)), ``scale`` one fp32 absmax scale per row as
+    a column ((B, 1) / (W, B, 1): the layout the fused kernels read).  ``shape``/``dtype`` remember the original per-entry
     leaf so :meth:`dequant` can restore it."""
 
     __slots__ = ("q", "scale", "shape", "dtype")
@@ -115,19 +116,43 @@ class QuantLeaf:
         return cls(children[0], children[1], aux[0], aux[1])
 
     def dequant(self):
-        """Entry-level (q (B, F), scale (B,)) -> the original leaf."""
-        x = self.q.astype(jnp.float32) * self.scale[:, None]
+        """Entry-level (q (B, F), scale (B, 1)) -> the original leaf."""
+        x = self.q.astype(jnp.float32) * self.scale
         return x.reshape(self.shape).astype(self.dtype)
+
+
+NIBBLE_GROUP = 128       # packed bytes per lane group (256 codes)
+
+
+def _groups(n: int):
+    """Split a row of ``n`` packed bytes into (full lane groups, rest)."""
+    return n // NIBBLE_GROUP, n % NIBBLE_GROUP
 
 
 def pack_nibbles(q):
     """Signed int4 codes (..., Fp) in [-7, 7] (Fp even) -> packed uint8
-    (..., Fp // 2).  Same bias-and-or layout as the PR-2 wire codec
-    (``compression.StochasticQuantCodec(bits=4)``): byte j holds element
-    2j in the low nibble and 2j + 1 in the high nibble, each biased by
-    +8 so the zero code is the nibble value 8."""
+    (..., Fp // 2), each code biased by +8 so the zero code is the nibble
+    value 8.
+
+    Lane-grouped layout: every 256 consecutive codes pack into 128
+    bytes, byte j holding code j of the group in its low nibble and code
+    j + 128 in its high nibble; a shorter last group of 2r codes splits
+    the same way at r.  A 128-lane block of packed bytes then unpacks on
+    the TPU into two contiguous 128-lane blocks of codes
+    (``kernels.fused_sample.unpack4``) — no lane interleave."""
     b = (q + 8).astype(jnp.uint8)                  # [-7, 7] -> [1, 15]
-    return b[..., 0::2] | (b[..., 1::2] << 4)
+    lead = b.shape[:-1]
+    n_full, rest = _groups(b.shape[-1] // 2)
+    parts = []
+    if n_full:
+        g = b[..., :n_full * 2 * NIBBLE_GROUP].reshape(
+            lead + (n_full, 2, NIBBLE_GROUP))
+        parts.append((g[..., 0, :] | (g[..., 1, :] << 4)).reshape(
+            lead + (n_full * NIBBLE_GROUP,)))
+    if rest:
+        t = b[..., n_full * 2 * NIBBLE_GROUP:]
+        parts.append(t[..., :rest] | (t[..., rest:] << 4))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, -1)
 
 
 def unpack_nibbles(packed):
@@ -135,7 +160,18 @@ def unpack_nibbles(packed):
     [-8, 7] fp32-safe int8 (the inverse of :func:`pack_nibbles`)."""
     lo = (packed & 0xF).astype(jnp.int8) - 8
     hi = (packed >> 4).astype(jnp.int8) - 8
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[:-1] + (-1,))
+    lead = packed.shape[:-1]
+    n_full, rest = _groups(packed.shape[-1])
+    parts = []
+    if n_full:
+        cut = n_full * NIBBLE_GROUP
+        g = jnp.stack([lo[..., :cut].reshape(lead + (n_full, NIBBLE_GROUP)),
+                       hi[..., :cut].reshape(lead + (n_full, NIBBLE_GROUP))],
+                      axis=-2)
+        parts.append(g.reshape(lead + (2 * cut,)))
+    if rest:
+        parts += [lo[..., -rest:], hi[..., -rest:]]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, -1)
 
 
 def _pad_even(F: int) -> int:
@@ -149,8 +185,8 @@ class Quant4Leaf:
     ``q`` holds packed uint8 bytes — two signed int4 codes (levels ±7)
     per byte — of the leaf flattened to (B, F) rows and F padded to even
     (entry level (B, ceil(F/2)); table level (W, B, ceil(F/2))).
-    ``scale`` is one fp32 absmax scale per row ((B,) / (W, B)), exactly
-    like :class:`QuantLeaf`.  The pad nibble stores code 0 so it decodes
+    ``scale`` is one fp32 absmax scale per row ((B, 1) / (W, B, 1)),
+    exactly like :class:`QuantLeaf`.  The pad nibble stores code 0 so it decodes
     to an exact zero; :meth:`dequant` slices it away."""
 
     __slots__ = ("q", "scale", "shape", "dtype")
@@ -169,13 +205,13 @@ class Quant4Leaf:
         return cls(children[0], children[1], aux[0], aux[1])
 
     def dequant(self):
-        """Entry-level (q (B, ceil(F/2)), scale (B,)) -> the original
+        """Entry-level (q (B, ceil(F/2)), scale (B, 1)) -> the original
         leaf."""
         F = 1
         for s in self.shape[1:]:
             F *= int(s)
         codes = unpack_nibbles(self.q)[:, :max(F, 1)]
-        x = codes.astype(jnp.float32) * self.scale[:, None]
+        x = codes.astype(jnp.float32) * self.scale
         return x.reshape(self.shape).astype(self.dtype)
 
 
@@ -215,7 +251,7 @@ def _row_shape(a) -> Tuple[int, int]:
 
 
 def _quantize_rows(rng, x2d, levels: int = 127):
-    """(B, F) fp32 -> (codes int8 (B, F), fp32 row scales (B,)); the fused
+    """(B, F) fp32 -> (codes int8 (B, F), fp32 row scales (B, 1)); the fused
     Pallas SR quantizer when the grid can tile B, its bit-identical jnp
     oracle otherwise.  ``levels`` is the max code magnitude (127 = int8 at
     rest, 7 = int4 at rest — the codes come back int8 either way; the int4
@@ -243,9 +279,10 @@ def _empty_store(W: int, a, cache_dtype: str):
         # keeps unpack(empty) == 0 too, matching the int8 empty table.
         return Quant4Leaf(jnp.full((W, B, _pad_even(F) // 2), 0x88,
                                    jnp.uint8),
-                          jnp.zeros((W, B), jnp.float32), a.shape, a.dtype)
+                          jnp.zeros((W, B, 1), jnp.float32), a.shape,
+                          a.dtype)
     return QuantLeaf(jnp.zeros((W, B, F), jnp.int8),
-                     jnp.zeros((W, B), jnp.float32), a.shape, a.dtype)
+                     jnp.zeros((W, B, 1), jnp.float32), a.shape, a.dtype)
 
 
 def _encode_leaf(store, x, rng):

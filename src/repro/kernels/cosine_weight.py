@@ -1,17 +1,22 @@
 """Fused staleness-weighting kernel (paper Algorithm 2 hot path).
 
 The naive composition (row-cosine, threshold, broadcast-multiply into ∇Z)
-makes three HBM round-trips over the (B, F) statistics.  This kernel fuses
-reduction + threshold + scale into ONE VMEM pass: each grid step loads a
-(BLOCK_B, F) tile of (ad_hoc, stale, dz), computes the row cosines on the
-VPU, and writes the weighted cotangent tile plus the (BLOCK_B,) weights.
+makes three HBM round-trips over the (B, F) statistics.  The kernels here
+and in ``fused_sample.py`` fuse reduction + threshold + scale into one
+VMEM pass over the operands.
 
 Layout decisions for TPU:
   * rows (instances) on the sublane axis, features on the lane axis — the
     row-reduction is a lane reduction, natively supported by the VPU;
-  * the feature dim is NOT tiled: VFL cut tensors are small per instance
-    (256 floats in the paper; ≤ d_model * S_block here), so a full row fits
-    VMEM comfortably and one-pass reduction avoids a two-phase scheme;
+  * the feature axis is a grid dimension.  A DLRM cut row (256 floats in
+    the paper) is one tile; a split-LLM row (F = S * d, ~half a million
+    floats) is cut into lane-aligned tiles of at most ``BLOCK_ELEMS``
+    elements per block, and the three row sums accumulate over the tiles
+    in a VMEM scratch before the threshold is applied;
+  * every per-row result (weights, scales) is a ``(rows, 1)`` column
+    block, which Mosaic lays out natively; the ops wrappers reshape the
+    weights to (B,);
+  * scalars (the threshold) live in SMEM;
   * fp32 accumulation regardless of input dtype (bf16 inputs upcast in
     VMEM).
 
@@ -19,89 +24,112 @@ Inputs of any rank are flattened to (B, F) by the ops.py wrapper.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_call
 
 EPS = 1e-12
 BLOCK_B = 128
+BLOCK_ELEMS = 128 * 1024     # fp32 elements per (rows, feature-tile) block
+LANES = 128
 
 
-def _row_weights(a_ref, s_ref, thresh_ref):
-    """Shared kernel body: row cosines floored at the threshold."""
-    a = a_ref[...].astype(jnp.float32)           # (BLOCK_B, F)
-    s = s_ref[...].astype(jnp.float32)
-    thresh = thresh_ref[0]
+def feature_tile(F: int, rows: int, width: int = 1) -> int:
+    """Feature-tile length for a (rows, F) operand.
 
-    num = jnp.sum(a * s, axis=1)                 # lane reduction -> (BLOCK_B,)
-    den = jnp.sqrt(jnp.sum(a * a, axis=1) * jnp.sum(s * s, axis=1))
-    w = num / jnp.maximum(den, EPS)
+    ``width`` is how many values one stored element expands to in VMEM
+    (2 for nibble-packed int4).  The whole row when ``rows * F * width``
+    fits one block (or F cannot be cut on lane boundaries); otherwise the
+    largest multiple of 128 lanes that divides F and fits."""
+    limit = max(LANES, BLOCK_ELEMS // (rows * width))
+    if F <= limit or F % LANES:
+        return F
+    t = limit // LANES * LANES
+    while F % t:
+        t -= LANES
+    return t
+
+
+def smem_scalar():
+    """BlockSpec of a (1,) scalar operand held in SMEM."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def row_sums_scratch(rows: int):
+    """VMEM accumulator of the three row sums (a·z, a·a, z·z)."""
+    return pltpu.VMEM((3, rows, 1), jnp.float32)
+
+
+def accumulate(acc_ref, k, a, z):
+    """Add one feature tile's row sums into the scratch (k == 0 starts
+    the row)."""
+    sums = (jnp.sum(a * z, axis=1, keepdims=True),
+            jnp.sum(a * a, axis=1, keepdims=True),
+            jnp.sum(z * z, axis=1, keepdims=True))
+
+    @pl.when(k == 0)
+    def _():
+        for j, s in enumerate(sums):
+            acc_ref[j] = s
+
+    @pl.when(k > 0)
+    def _():
+        for j, s in enumerate(sums):
+            acc_ref[j] = acc_ref[j] + s
+
+
+def row_weights(acc_ref, thresh):
+    """Completed row sums -> (rows, 1) cosines floored at ``thresh``."""
+    den = jnp.sqrt(acc_ref[1] * acc_ref[2])
+    w = acc_ref[0] / jnp.maximum(den, EPS)
     return jnp.where(w < thresh, 0.0, w)
 
 
-def _kernel(a_ref, s_ref, dz_ref, thresh_ref, w_ref, out_ref):
-    w = _row_weights(a_ref, s_ref, thresh_ref)
-    dz = dz_ref[...].astype(jnp.float32)
-    w_ref[...] = w
-    out_ref[...] = (dz * w[:, None]).astype(out_ref.dtype)
+def _kernel_weights_only(thresh_ref, a_ref, s_ref, w_ref, acc_ref):
+    k = pl.program_id(1)
+    accumulate(acc_ref, k, a_ref[...].astype(jnp.float32),
+               s_ref[...].astype(jnp.float32))
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _():
+        w_ref[...] = row_weights(acc_ref, thresh_ref[0])
 
 
-def _kernel_weights_only(a_ref, s_ref, thresh_ref, w_ref):
-    w_ref[...] = _row_weights(a_ref, s_ref, thresh_ref)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def cosine_weights_2d(ad_hoc, stale, cos_xi, *, interpret: bool = True):
-    """Weights-only variant: loads 2 (B, F) operands, writes only the (B,)
-    weights — for the label party's InsWeight, where no cotangent scale
-    follows (the weighted loss drives the backward pass instead)."""
+@jax.jit
+def cosine_weights_2d(ad_hoc, stale, cos_xi):
+    """Weights-only variant: loads 2 (B, F) operands, writes only the
+    (B, 1) weights — for the label party's InsWeight, where no cotangent
+    scale follows (the weighted loss drives the backward pass instead)."""
     B, F = ad_hoc.shape
     bb = min(BLOCK_B, B)
     assert B % bb == 0, (B, bb)
+    tf = feature_tile(F, bb)
     thresh = jnp.asarray([cos_xi], jnp.float32)
-
-    return pl.pallas_call(
+    return pallas_call(
         _kernel_weights_only,
-        grid=(B // bb,),
+        grid=(B // bb, F // tf),
         in_specs=[
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
+            smem_scalar(),
+            pl.BlockSpec((bb, tf), lambda i, k: (i, k)),
+            pl.BlockSpec((bb, tf), lambda i, k: (i, k)),
         ],
-        out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.float32),
-        interpret=interpret,
-    )(ad_hoc, stale, thresh)
+        out_specs=pl.BlockSpec((bb, 1), lambda i, k: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
+        scratch_shapes=[row_sums_scratch(bb)],
+        name="cosine_weights",
+    )(thresh, ad_hoc, stale)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def cosine_weight_2d(ad_hoc, stale, dz, cos_xi, *, interpret: bool = True):
-    """ad_hoc, stale, dz: (B, F).  -> (weights (B,) f32, weighted dz)."""
-    B, F = ad_hoc.shape
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    thresh = jnp.asarray([cos_xi], jnp.float32)
+@jax.jit
+def cosine_weight_2d(ad_hoc, stale, dz, cos_xi):
+    """ad_hoc, stale, dz: (B, F).  -> (weights (B, 1) f32, weighted dz in
+    dz's dtype).  The fused-sample kernel over a one-slot ring: the same
+    two-phase body (row sums over the feature tiles, then the scale)."""
+    from .fused_sample import fused_sample_2d
+    w, out = fused_sample_2d(jnp.zeros((1,), jnp.int32), ad_hoc,
+                             stale[None], dz[None], cos_xi)
+    return w, out.astype(dz.dtype)
 
-    grid = (B // bb,)
-    w, out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bb,), lambda i: (i,)),
-            pl.BlockSpec((bb, F), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B, F), dz.dtype),
-        ],
-        interpret=interpret,
-    )(ad_hoc, stale, dz, thresh)
-    return w, out

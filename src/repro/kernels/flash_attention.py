@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_call
+
 NEG_INF = -1e30
 BLOCK_Q = 256
 BLOCK_K = 256
@@ -52,10 +54,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
 
     def body(ki, carry):
         acc, m, l = carry
-        ks = pl.load(k_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
-        vs = pl.load(v_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
+        ks = k_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+        vs = v_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -83,9 +83,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("causal", "window"))
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
     """q, k, v: (B, S, H, hd) (kv already repeated to H).  -> (B, S, H, hd).
 
     S must be a multiple of BLOCK_Q/BLOCK_K (pad upstream if not).
@@ -102,7 +101,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(_kernel, block_k=bk, causal=causal,
                                window=window, seq_len=S)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=(B * H, S // bq),
         in_specs=[
@@ -112,6 +111,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

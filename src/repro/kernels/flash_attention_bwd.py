@@ -16,7 +16,7 @@ sliding-window masking mirrors the forward with the same block-skipping
 bounds.  fp32 accumulation throughout.
 
 ``flash_attention_vjp`` is a jax.custom_vjp function validated against
-``jax.grad`` of the pure-jnp oracle in tests (interpret mode).
+``jax.grad`` of the pure-jnp oracle in tests.
 """
 from __future__ import annotations
 
@@ -25,6 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import pallas_call
 
 NEG_INF = -1e30
 BLOCK_Q = 256
@@ -48,10 +50,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     def body(ki, carry):
         acc, m, l = carry
-        ks = pl.load(k_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
-        vs = pl.load(v_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
+        ks = k_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+        vs = v_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -78,7 +78,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     acc, m, l = jax.lax.fori_loop(lo, hi, body, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[...] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[...] = m + jnp.log(l_safe)
+    lse_ref[...] = (m + jnp.log(l_safe))[:, None]     # (bq, 1) column
 
 
 # --------------------------------------------------------------------------
@@ -102,12 +102,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(qi, carry):
         dk, dv = carry
-        qs = pl.load(q_ref, (pl.dslice(qi * block_q, block_q),
-                             pl.dslice(None))).astype(jnp.float32)
-        dos = pl.load(do_ref, (pl.dslice(qi * block_q, block_q),
-                               pl.dslice(None))).astype(jnp.float32)
-        lse = pl.load(lse_ref, (pl.dslice(qi * block_q, block_q),))
-        delta = pl.load(delta_ref, (pl.dslice(qi * block_q, block_q),))
+        qs = q_ref[pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
+        dos = do_ref[pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
+        lse = lse_ref[pl.ds(qi * block_q, block_q), :]       # (bq_, 1)
+        delta = delta_ref[pl.ds(qi * block_q, block_q), :]
         s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = qi * block_q + jax.lax.broadcasted_iota(
@@ -118,13 +116,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask &= d >= 0
         if window:
             mask &= d < window
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)  # (bq_, bk)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)           # (bq_, bk)
         dv_new = dv + jax.lax.dot_general(
             p, dos, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(dos, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_new = dk + jax.lax.dot_general(
             ds, qs, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -153,10 +151,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     lo = jnp.maximum((qi * bq - window) // block_k, 0) if window else 0
 
     def body(ki, dq):
-        ks = pl.load(k_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
-        vs = pl.load(v_ref, (pl.dslice(ki * block_k, block_k),
-                             pl.dslice(None))).astype(jnp.float32)
+        ks = k_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+        vs = v_ref[pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -167,10 +163,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             mask &= d >= 0
         if window:
             mask &= d < window
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, vs, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         return dq + jax.lax.dot_general(
             ds, ks, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -192,38 +188,38 @@ def _unfold(x, B, H):
     return x.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
 
 
-def _fwd(q, k, v, causal, window, interpret):
+def _fwd(q, k, v, causal, window):
     B, S, H, hd = q.shape
     bq = min(BLOCK_Q, S)
     bk = min(BLOCK_K, S)
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
     kernel = functools.partial(_fwd_kernel, block_k=bk, causal=causal,
                                window=window, seq_len=S)
-    o, lse = pl.pallas_call(
+    o, lse = pallas_call(
         kernel,
         grid=(B * H, S // bq),
         in_specs=[pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((None, S, hd), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((None, S, hd), lambda b, i: (b, 0, 0))],
         out_specs=[pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((None, bq), lambda b, i: (b, i))],
+                   pl.BlockSpec((None, bq, 1), lambda b, i: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.float32)],
-        interpret=interpret,
+                   jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32)],
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     return o, lse
 
 
-def _bwd(q, k, v, o, lse, do, causal, window, interpret):
+def _bwd(q, k, v, o, lse, do, causal, window):
     B, S, H, hd = q.shape
     bq = min(BLOCK_Q, S)
     bk = min(BLOCK_K, S)
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
     of, dof = _fold(o), _fold(do)
     delta = jnp.sum(of.astype(jnp.float32) * dof.astype(jnp.float32),
-                    axis=-1)                       # (BH, S)
+                    axis=-1, keepdims=True)        # (BH, S, 1)
 
-    dkv = pl.pallas_call(
+    dkv = pallas_call(
         functools.partial(_dkv_kernel, block_q=bq, causal=causal,
                           window=window, seq_len=S),
         grid=(B * H, S // bk),
@@ -231,17 +227,17 @@ def _bwd(q, k, v, o, lse, do, causal, window, interpret):
                   pl.BlockSpec((None, bk, hd), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((None, bk, hd), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((None, S, hd), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((None, S), lambda b, i: (b, 0)),
-                  pl.BlockSpec((None, S), lambda b, i: (b, 0))],
+                  pl.BlockSpec((None, S, 1), lambda b, i: (b, 0, 0)),
+                  pl.BlockSpec((None, S, 1), lambda b, i: (b, 0, 0))],
         out_specs=[pl.BlockSpec((None, bk, hd), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((None, bk, hd), lambda b, i: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
                    jax.ShapeDtypeStruct((B * H, S, hd), q.dtype)],
-        interpret=interpret,
+        name="flash_attention_dkv",
     )(qf, kf, vf, dof, lse, delta)
     dk, dv = dkv
 
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_dq_kernel, block_k=bk, causal=causal,
                           window=window, seq_len=S),
         grid=(B * H, S // bq),
@@ -249,31 +245,30 @@ def _bwd(q, k, v, o, lse, do, causal, window, interpret):
                   pl.BlockSpec((None, S, hd), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((None, S, hd), lambda b, i: (b, 0, 0)),
                   pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((None, bq), lambda b, i: (b, i)),
-                  pl.BlockSpec((None, bq), lambda b, i: (b, i))],
+                  pl.BlockSpec((None, bq, 1), lambda b, i: (b, i, 0)),
+                  pl.BlockSpec((None, bq, 1), lambda b, i: (b, i, 0))],
         out_specs=pl.BlockSpec((None, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, dof, lse, delta)
     return (_unfold(dq, B, H), _unfold(dk, B, H), _unfold(dv, B, H))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention_vjp(q, k, v, causal: bool = True, window: int = 0,
-                        interpret: bool = True):
-    o, _ = _fwd(q, k, v, causal, window, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_attention_vjp(q, k, v, causal: bool = True, window: int = 0):
+    o, _ = _fwd(q, k, v, causal, window)
     return _unfold(o, q.shape[0], q.shape[2])
 
 
-def _vjp_fwd(q, k, v, causal, window, interpret):
-    o, lse = _fwd(q, k, v, causal, window, interpret)
+def _vjp_fwd(q, k, v, causal, window):
+    o, lse = _fwd(q, k, v, causal, window)
     return _unfold(o, q.shape[0], q.shape[2]), (q, k, v, o, lse)
 
 
-def _vjp_bwd(causal, window, interpret, res, g):
+def _vjp_bwd(causal, window, res, g):
     q, k, v, of, lse = res
     o = _unfold(of, q.shape[0], q.shape[2])
-    return _bwd(q, k, v, o, lse, g, causal, window, interpret)
+    return _bwd(q, k, v, o, lse, g, causal, window)
 
 
 flash_attention_vjp.defvjp(_vjp_fwd, _vjp_bwd)

@@ -28,11 +28,12 @@ silently stalling.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import pallas_call
+from .cosine_weight import smem_scalar
 
 BLOCK = 1024
 ROWS = 8
@@ -50,8 +51,8 @@ def _kernel(g_ref, a_ref, hyp_ref, u_ref, a_out_ref):
     a_out_ref[...] = a_new
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_adagrad(grad, accum, lr, eps, *, interpret: bool = True):
+@jax.jit
+def fused_adagrad(grad, accum, lr, eps):
     """grad: any shape/dtype; accum: same shape fp32.
     -> (update fp32, new_accum fp32), same shape as grad."""
     shape = grad.shape
@@ -69,13 +70,13 @@ def fused_adagrad(grad, accum, lr, eps, *, interpret: bool = True):
         accum.reshape(-1)).reshape(n_rows_pad, cols)
     hyp = jnp.asarray([lr, eps], jnp.float32)
 
-    u, a_new = pl.pallas_call(
+    u, a_new = pallas_call(
         _kernel,
         grid=(n_rows_pad // rows_per_block,),
         in_specs=[
             pl.BlockSpec((rows_per_block, cols), lambda i: (i, 0)),
             pl.BlockSpec((rows_per_block, cols), lambda i: (i, 0)),
-            pl.BlockSpec((2,), lambda i: (0,)),
+            smem_scalar(),
         ],
         out_specs=[
             pl.BlockSpec((rows_per_block, cols), lambda i: (i, 0)),
@@ -85,7 +86,7 @@ def fused_adagrad(grad, accum, lr, eps, *, interpret: bool = True):
             jax.ShapeDtypeStruct((n_rows_pad, cols), jnp.float32),
             jax.ShapeDtypeStruct((n_rows_pad, cols), jnp.float32),
         ],
-        interpret=interpret,
+        name="fused_adagrad",
     )(g, a, hyp)
     return (u.reshape(-1)[:n].reshape(shape),
             a_new.reshape(-1)[:n].reshape(shape))
@@ -106,9 +107,8 @@ def _kernel_q8(g_ref, q_ref, s_ref, u_ref, hyp_ref, upd_ref, q_out_ref,
     s_out_ref[...] = s_new
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_adagrad_q8(grad2d, accum_q, accum_scale, u, lr, eps, *,
-                     interpret: bool = True):
+@jax.jit
+def fused_adagrad_q8(grad2d, accum_q, accum_scale, u, lr, eps):
     """int8-at-rest AdaGrad step over the kernel's native tiling.
 
     grad2d: (R, C) fp32 with R % ROWS == 0 (the optimizer pads once at
@@ -120,7 +120,7 @@ def fused_adagrad_q8(grad2d, accum_q, accum_scale, u, lr, eps, *,
     R, C = grad2d.shape
     assert R % ROWS == 0, (R, ROWS)
     hyp = jnp.asarray([lr, eps], jnp.float32)
-    return pl.pallas_call(
+    return pallas_call(
         _kernel_q8,
         grid=(R // ROWS,),
         in_specs=[
@@ -128,7 +128,7 @@ def fused_adagrad_q8(grad2d, accum_q, accum_scale, u, lr, eps, *,
             pl.BlockSpec((ROWS, C), lambda i: (i, 0)),
             pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
             pl.BlockSpec((ROWS, C), lambda i: (i, 0)),
-            pl.BlockSpec((2,), lambda i: (0,)),
+            smem_scalar(),
         ],
         out_specs=[
             pl.BlockSpec((ROWS, C), lambda i: (i, 0)),
@@ -140,6 +140,6 @@ def fused_adagrad_q8(grad2d, accum_q, accum_scale, u, lr, eps, *,
             jax.ShapeDtypeStruct((R, C), jnp.int8),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
-        interpret=interpret,
+        name="fused_adagrad_q8",
     )(grad2d.astype(jnp.float32), accum_q, accum_scale,
       u.astype(jnp.float32), hyp)

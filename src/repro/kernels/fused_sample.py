@@ -14,149 +14,187 @@ dominant on-device loop once the wire is compressed and pipelined.
 Layout decisions for TPU:
   * the dynamic ring slot rides in as a SCALAR-PREFETCH operand
     (``pltpu.PrefetchScalarGridSpec``): the BlockSpec index maps consume it
-    before the body runs, so only the selected slot's (BLOCK_B, F) blocks
-    are ever DMA'd — the gather happens at the block-fetch level, no
-    HBM-side entry copy exists;
+    before the body runs, so only the selected slot's blocks are ever
+    DMA'd — the gather happens at the block-fetch level, no HBM-side entry
+    copy exists;
   * rows (instances) on the sublane axis, the flattened feature dim on the
-    lane axis, NOT tiled (same choice as ``cosine_weight.py``: VFL cut
-    tensors are small per instance, a full row fits VMEM) — so the
-    int8 cache's one-fp32-scale-per-row dequantizes as a lane broadcast;
+    lane axis, cut into lane-aligned feature tiles
+    (``cosine_weight.feature_tile``).  The grid is (row blocks, phase,
+    feature tiles): phase 0 accumulates the three row sums over the tiles
+    of a ∗ z, phase 1 walks the ∇Z tiles and writes the weighted
+    cotangent once the row's weight is known.  Index maps hold the block
+    a phase does not need, so no operand is fetched twice.  A row that is
+    one tile (the paper's DLRM rows) runs both phases in one step;
+  * the int8 cache's one-fp32-scale-per-row is a (rows, 1) column block
+    ((W, B, 1) at rest) and dequantizes as a lane broadcast;
   * fp32 compute regardless of storage dtype (int8/bf16 upcast in VMEM);
-    the fp32-ring variant reproduces ``cosine_weight._kernel`` bit-for-bit
-    (same reduction order over the same blocks — the golden traces pin
-    this through the engine).
+    the fp32-ring variant reproduces ``kernels.ref`` bit-for-bit when a
+    row is one tile (same reduction over the same blocks — the golden
+    traces pin this through the engine).  Rows cut into several tiles
+    sum the per-tile partial sums, which reassociates the float32 row
+    reduction.
 
-Oracles: ``kernels.ref.fused_sample_ref`` / ``fused_sample_q8_ref``.
-B not divisible by BLOCK_B falls back to the reference composition in the
-engine (same rule as the weighting kernel).
+Oracles: ``kernels.ref.fused_sample_ref`` / ``fused_sample_q8_ref`` /
+``fused_sample_q4_ref``.  B not divisible by BLOCK_B falls back to the
+reference composition in the engine (same rule as the weighting kernel).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .cosine_weight import BLOCK_B, EPS
+from . import pallas_call
+from .cosine_weight import (BLOCK_B, LANES, accumulate, feature_tile,
+                            row_sums_scratch, row_weights, smem_scalar)
 
 
-def _weight_and_scale(a, z, dz, thresh):
-    """Shared body: row cosine floored at thresh, cotangent scale.
-    All operands (BLOCK_B, F) fp32 in VMEM."""
-    num = jnp.sum(a * z, axis=1)             # lane reduction -> (BLOCK_B,)
-    den = jnp.sqrt(jnp.sum(a * a, axis=1) * jnp.sum(z * z, axis=1))
-    w = num / jnp.maximum(den, EPS)
-    w = jnp.where(w < thresh, 0.0, w)
-    return w, dz * w[:, None]
+def _decode_f32(z_ref):
+    return z_ref[...].astype(jnp.float32)
 
 
-def _kernel_f32(slot_ref, a_ref, z_ref, dz_ref, thresh_ref, w_ref, out_ref):
-    del slot_ref                             # consumed by the index maps
-    a = a_ref[...].astype(jnp.float32)       # (BLOCK_B, F)
-    z = z_ref[0].astype(jnp.float32)         # (1, BLOCK_B, F) ring block
-    dz = dz_ref[0].astype(jnp.float32)
-    w, cot = _weight_and_scale(a, z, dz, thresh_ref[0])
-    w_ref[...] = w
-    out_ref[...] = cot
+def _decode_q8(q_ref, s_ref):
+    return q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def _kernel_q8(slot_ref, a_ref, zq_ref, zs_ref, dzq_ref, dzs_ref,
-               thresh_ref, w_ref, out_ref):
-    del slot_ref
-    a = a_ref[...].astype(jnp.float32)
-    z = zq_ref[0].astype(jnp.float32) * zs_ref[0][:, None]    # dequant
-    dz = dzq_ref[0].astype(jnp.float32) * dzs_ref[0][:, None]
-    w, cot = _weight_and_scale(a, z, dz, thresh_ref[0])
-    w_ref[...] = w
-    out_ref[...] = cot
+def unpack4(packed):
+    """(rows, P) packed uint8 -> (rows, 2P) fp32 int4 codes, in VMEM.
+
+    The at-rest nibble layout (``core.workset.pack_nibbles``) packs each
+    group of 256 codes into 128 bytes: byte j holds code j in its low
+    nibble and code j + 128 in its high nibble (a shorter last group
+    splits its codes in halves the same way).  Unpacking is then two
+    nibble extractions and lane-aligned concatenation.  The bytes are
+    widened to int32 first: the VPU has no 8-bit arithmetic."""
+    w = packed.astype(jnp.int32)
+    lo = ((w & 0xF) - 8).astype(jnp.float32)
+    hi = ((w >> 4) - 8).astype(jnp.float32)
+    P = packed.shape[1]
+    parts = []
+    for g in range(0, P, LANES):
+        e = min(g + LANES, P)
+        parts += [lo[:, g:e], hi[:, g:e]]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
 
 
-def _unpack4(packed):
-    """(BLOCK_B, F/2) packed uint8 -> (BLOCK_B, F) fp32 int4 codes, in
-    VMEM (byte j: element 2j low nibble, 2j+1 high — the wire codec's
-    layout; see ``core.workset.unpack_nibbles``)."""
-    lo = (packed & 0xF).astype(jnp.int8) - 8
-    hi = (packed >> 4).astype(jnp.int8) - 8
-    both = jnp.stack([lo, hi], axis=-1)          # (bb, F/2, 2)
-    return both.reshape(packed.shape[0], -1).astype(jnp.float32)
+def _decode_q4(q_ref, s_ref):
+    return unpack4(q_ref[...]) * s_ref[...]
 
 
-def _kernel_q4(slot_ref, a_ref, zq_ref, zs_ref, dzq_ref, dzs_ref,
-               thresh_ref, w_ref, out_ref):
-    """int4 ring block: unpack the nibbles in VMEM, dequant against the
-    per-row scale, then the shared weight-and-scale body.  No unpacked
-    entry ever exists in HBM — the packed bytes are the only ring read."""
-    del slot_ref
-    a = a_ref[...].astype(jnp.float32)
-    z = _unpack4(zq_ref[0]) * zs_ref[0][:, None]
-    dz = _unpack4(dzq_ref[0]) * dzs_ref[0][:, None]
-    w, cot = _weight_and_scale(a, z, dz, thresh_ref[0])
-    w_ref[...] = w
-    out_ref[...] = cot
+def _sample_kernel(decode, n_ring: int, two_pass: bool):
+    """Kernel over the (row block, phase, feature tile) grid.  ``decode``
+    turns one ring operand's refs (``n_ring`` of them) into fp32 rows."""
+    def kernel(slot_ref, thresh_ref, a_ref, *refs):
+        del slot_ref                         # consumed by the index maps
+        z_refs, dz_refs = refs[:n_ring], refs[n_ring:2 * n_ring]
+        w_ref, out_ref, acc_ref = refs[2 * n_ring:]
+        p, k = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(p == 0)
+        def _():
+            accumulate(acc_ref, k, a_ref[...].astype(jnp.float32),
+                       decode(*z_refs))
+
+        @pl.when(p == int(two_pass))         # the row sums are complete
+        def _():
+            w = row_weights(acc_ref, thresh_ref[0])
+            w_ref[...] = w
+            out_ref[...] = decode(*dz_refs) * w
+    return kernel
 
 
-def _call(kernel, slot, operands, ring_specs, B, F, bb, interpret):
-    """Common pallas_call plumbing: scalar-prefetch slot + (bb, F) ad-hoc
-    blocks + per-ring slot-indexed blocks + (1,) threshold."""
+def _sample(decode, slot, ad_hoc, z_ops, dz_ops, cos_xi, *, tile: int,
+            packed: bool = False, name: str):
+    """pallas_call plumbing shared by the ring variants.
+
+    ``z_ops`` / ``dz_ops``: the ring's arrays — (W, B, Fs) codes or
+    values, plus a (W, B, 1) scale column for quantized rings.  ``tile``
+    is the feature tile in STORED elements (packed bytes for int4, where
+    one byte holds two codes)."""
+    B, F = ad_hoc.shape
+    Fs = z_ops[0].shape[2]
+    bb = min(BLOCK_B, B)
+    assert B % bb == 0, (B, bb)
+    nk = Fs // tile
+    two_pass = nk > 1
+    ta = 2 * tile if packed else tile        # ad_hoc / cotangent tile
+
+    # phase 0 walks a/z over the tiles and holds dz at tile 0; phase 1
+    # holds a/z at the last tile and walks dz (each block fetched once)
+    def first(p, k):
+        return k + p * (nk - 1 - k)
+
+    def second(p, k):
+        return k * p
+
+    def ring_specs(ops, tiles):
+        specs = [pl.BlockSpec((None, bb, tile),
+                              lambda i, p, k, s: (s[0], i, tiles(p, k)))]
+        if len(ops) == 2:                    # (W, B, 1) row scales
+            specs.append(pl.BlockSpec((None, bb, 1),
+                                      lambda i, p, k, s: (s[0], i, 0)))
+        return specs
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B // bb,),
-        in_specs=[pl.BlockSpec((bb, F), lambda i, s: (i, 0))] + ring_specs +
-                 [pl.BlockSpec((1,), lambda i, s: (0,))],
+        grid=(B // bb, 2 if two_pass else 1, nk),
+        in_specs=[smem_scalar(),
+                  pl.BlockSpec((bb, ta),
+                               lambda i, p, k, s: (i, first(p, k)))]
+        + ring_specs(z_ops, first) + ring_specs(dz_ops, second),
         out_specs=[
-            pl.BlockSpec((bb,), lambda i, s: (i,)),
-            pl.BlockSpec((bb, F), lambda i, s: (i, 0)),
+            pl.BlockSpec((bb, 1), lambda i, p, k, s: (i, 0)),
+            pl.BlockSpec((bb, ta), lambda i, p, k, s: (i, second(p, k))),
         ],
+        scratch_shapes=[row_sums_scratch(bb)],
     )
-    return pl.pallas_call(
-        kernel,
+    thresh = jnp.asarray([cos_xi], jnp.float32)
+    return pallas_call(
+        _sample_kernel(decode, len(z_ops), two_pass),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, F), jnp.float32),
         ],
-        interpret=interpret,
-    )(slot, *operands)
+        name=name,
+    )(slot, thresh, ad_hoc, *z_ops, *dz_ops)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_sample_2d(slot, ad_hoc, z_ring, dz_ring, cos_xi, *,
-                    interpret: bool = True):
+@jax.jit
+def fused_sample_2d(slot, ad_hoc, z_ring, dz_ring, cos_xi):
     """Full-precision ring.  slot: (1,) int32; ad_hoc: (B, F); z_ring /
-    dz_ring: (W, B, F).  -> (weights (B,) f32, weighted cotangent (B, F)
-    f32) for the entry at ``slot``."""
-    W, B, F = z_ring.shape
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    thresh = jnp.asarray([cos_xi], jnp.float32)
-    ring = [
-        pl.BlockSpec((1, bb, F), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb, F), lambda i, s: (s[0], i, 0)),
-    ]
-    return _call(_kernel_f32, slot, (ad_hoc, z_ring, dz_ring, thresh),
-                 ring, B, F, bb, interpret)
+    dz_ring: (W, B, F).  -> (weights (B, 1) f32, weighted cotangent
+    (B, F) f32) for the entry at ``slot``."""
+    B, F = ad_hoc.shape
+    return _sample(_decode_f32, slot, ad_hoc, (z_ring,), (dz_ring,),
+                   cos_xi, tile=feature_tile(F, min(BLOCK_B, B)),
+                   name="fused_sample")
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_sample_q8_2d(slot, ad_hoc, zq, zscale, dzq, dzscale, cos_xi, *,
-                       interpret: bool = True):
+@jax.jit
+def fused_sample_q8_2d(slot, ad_hoc, zq, zscale, dzq, dzscale, cos_xi):
     """int8-at-rest ring.  zq / dzq: (W, B, F) int8 codes, zscale /
-    dzscale: (W, B) fp32 per-row scales.  Same contract as
+    dzscale: (W, B, 1) fp32 per-row scales.  Same contract as
     :func:`fused_sample_2d`; dequantization happens in VMEM."""
-    W, B, F = zq.shape
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    thresh = jnp.asarray([cos_xi], jnp.float32)
-    ring = [
-        pl.BlockSpec((1, bb, F), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-        pl.BlockSpec((1, bb, F), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-    ]
-    return _call(_kernel_q8, slot, (ad_hoc, zq, zscale, dzq, dzscale,
-                                    thresh), ring, B, F, bb, interpret)
+    B, F = ad_hoc.shape
+    return _sample(_decode_q8, slot, ad_hoc, (zq, zscale), (dzq, dzscale),
+                   cos_xi, tile=feature_tile(F, min(BLOCK_B, B)),
+                   name="fused_sample_q8")
+
+
+@jax.jit
+def fused_sample_q4_2d(slot, ad_hoc, zq, zscale, dzq, dzscale, cos_xi):
+    """int4 nibble-packed ring.  zq / dzq: (W, B, F // 2) packed uint8
+    (F even — the storage codec pads odd rows; the caller pads ``ad_hoc``
+    to match), zscale / dzscale: (W, B, 1) fp32 per-row scales.  Same
+    contract as :func:`fused_sample_2d`; unpack + dequant happen in VMEM
+    so the packed bytes are the only HBM ring traffic."""
+    W, B, P = zq.shape
+    assert ad_hoc.shape == (B, 2 * P), (ad_hoc.shape, B, P)
+    return _sample(_decode_q4, slot, ad_hoc, (zq, zscale), (dzq, dzscale),
+                   cos_xi, tile=feature_tile(P, min(BLOCK_B, B), width=2),
+                   packed=True, name="fused_sample_q4")
 
 
 # --------------------------------------------------------------------------
@@ -166,87 +204,52 @@ def fused_sample_q8_2d(slot, ad_hoc, zq, zscale, dzq, dzscale, cos_xi, *,
 # bare dequant: serving consumes the cached cross-party activation as-is
 # (there is no ad-hoc statistic to cosine-weight against at decode time).
 # --------------------------------------------------------------------------
-def _kernel_dq8(slot_ref, zq_ref, zs_ref, out_ref):
-    del slot_ref                             # consumed by the index maps
-    out_ref[...] = zq_ref[0].astype(jnp.float32) * zs_ref[0][:, None]
+def _dequant_kernel(decode):
+    def kernel(slot_ref, q_ref, s_ref, out_ref):
+        del slot_ref                         # consumed by the index maps
+        out_ref[...] = decode(q_ref, s_ref)
+    return kernel
 
 
-def _kernel_dq4(slot_ref, zq_ref, zs_ref, out_ref):
-    del slot_ref
-    out_ref[...] = _unpack4(zq_ref[0]) * zs_ref[0][:, None]
-
-
-def _call_dequant(kernel, slot, operands, ring_specs, B, F, bb, interpret):
-    """pallas_call plumbing for the dequant-only kernels: scalar-prefetch
-    slot + per-ring slot-indexed blocks -> one (B, F) fp32 output."""
+def _dequant(decode, slot, zq, zscale, *, width: int, name: str):
+    """(W, B, Fs) codes + (W, B, 1) scales -> (B, width * Fs) fp32 rows of
+    the entry at ``slot``, tiled over rows and features."""
+    W, B, Fs = zq.shape
+    bb = min(BLOCK_B, B)
+    assert B % bb == 0, (B, bb)
+    tile = feature_tile(Fs, bb, width)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B // bb,),
-        in_specs=ring_specs,
-        out_specs=pl.BlockSpec((bb, F), lambda i, s: (i, 0)),
+        grid=(B // bb, Fs // tile),
+        in_specs=[
+            pl.BlockSpec((None, bb, tile), lambda i, k, s: (s[0], i, k)),
+            pl.BlockSpec((None, bb, 1), lambda i, k, s: (s[0], i, 0)),
+        ],
+        out_specs=pl.BlockSpec((bb, width * tile), lambda i, k, s: (i, k)),
     )
-    return pl.pallas_call(
-        kernel,
+    return pallas_call(
+        _dequant_kernel(decode),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, F), jnp.float32),
-        interpret=interpret,
-    )(slot, *operands)
+        out_shape=jax.ShapeDtypeStruct((B, width * Fs), jnp.float32),
+        name=name,
+    )(slot, zq, zscale)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_dequant_q8_2d(slot, zq, zscale, *, interpret: bool = True):
+@jax.jit
+def fused_dequant_q8_2d(slot, zq, zscale):
     """Gather + dequantize ONE int8 ring entry.  slot: (1,) int32; zq:
-    (W, B, F) int8 codes, zscale: (W, B) fp32 per-row scales.  -> (B, F)
-    fp32 rows of the entry at ``slot``; no full-precision ring copy ever
-    exists in HBM."""
-    W, B, F = zq.shape
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    ring = [
-        pl.BlockSpec((1, bb, F), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-    ]
-    return _call_dequant(_kernel_dq8, slot, (zq, zscale), ring, B, F, bb,
-                         interpret)
+    (W, B, F) int8 codes, zscale: (W, B, 1) fp32 per-row scales.
+    -> (B, F) fp32 rows of the entry at ``slot``; no full-precision ring
+    copy ever exists in HBM."""
+    return _dequant(_decode_q8, slot, zq, zscale, width=1,
+                    name="fused_dequant_q8")
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_dequant_q4_2d(slot, zq, zscale, *, interpret: bool = True):
+@jax.jit
+def fused_dequant_q4_2d(slot, zq, zscale):
     """Gather + unpack + dequantize ONE int4 nibble-packed ring entry.
-    zq: (W, B, F // 2) packed uint8, zscale: (W, B) fp32 row scales.
+    zq: (W, B, F // 2) packed uint8, zscale: (W, B, 1) fp32 row scales.
     -> (B, F) fp32 (F = 2 * packed width; the caller slices any pad
     column)."""
-    W, B, P = zq.shape
-    F = 2 * P
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    ring = [
-        pl.BlockSpec((1, bb, P), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-    ]
-    return _call_dequant(_kernel_dq4, slot, (zq, zscale), ring, B, F, bb,
-                         interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_sample_q4_2d(slot, ad_hoc, zq, zscale, dzq, dzscale, cos_xi, *,
-                       interpret: bool = True):
-    """int4 nibble-packed ring.  zq / dzq: (W, B, F // 2) packed uint8
-    (F even — the storage codec pads odd rows; the caller pads ``ad_hoc``
-    to match), zscale / dzscale: (W, B) fp32 per-row scales.  Same
-    contract as :func:`fused_sample_2d`; unpack + dequant happen in VMEM
-    so the packed bytes are the only HBM ring traffic."""
-    W, B, P = zq.shape
-    F = 2 * P
-    assert ad_hoc.shape == (B, F), (ad_hoc.shape, B, F)
-    bb = min(BLOCK_B, B)
-    assert B % bb == 0, (B, bb)
-    thresh = jnp.asarray([cos_xi], jnp.float32)
-    ring = [
-        pl.BlockSpec((1, bb, P), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-        pl.BlockSpec((1, bb, P), lambda i, s: (s[0], i, 0)),
-        pl.BlockSpec((1, bb), lambda i, s: (s[0], i)),
-    ]
-    return _call(_kernel_q4, slot, (ad_hoc, zq, zscale, dzq, dzscale,
-                                    thresh), ring, B, F, bb, interpret)
+    return _dequant(_decode_q4, slot, zq, zscale, width=2,
+                    name="fused_dequant_q4")

@@ -1,8 +1,8 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets).
 
 Each function is the mathematically-plain composition that the fused kernel
-must reproduce; tests sweep shapes/dtypes and assert kernel(interpret=True)
-against these.
+must reproduce; tests sweep shapes/dtypes and assert the kernels (run by
+the Pallas interpreter on the CPU) against these.
 """
 from __future__ import annotations
 
@@ -50,9 +50,10 @@ def fused_sample_ref(slot, ad_hoc, z_ring, dz_ring, cos_xi: float):
 def fused_sample_q8_ref(slot, ad_hoc, zq, zscale, dzq, dzscale,
                         cos_xi: float):
     """int8-ring oracle: dequantize the sampled rows (codes * per-row
-    scale), then the fp32 composition of :func:`fused_sample_ref`."""
-    z = zq[slot].astype(jnp.float32) * zscale[slot][:, None]
-    dz = dzq[slot].astype(jnp.float32) * dzscale[slot][:, None]
+    (W, B, 1) scale), then the fp32 composition of
+    :func:`fused_sample_ref`."""
+    z = zq[slot].astype(jnp.float32) * zscale[slot]
+    dz = dzq[slot].astype(jnp.float32) * dzscale[slot]
     B = ad_hoc.shape[0]
     w = cosine_weight_ref(ad_hoc.reshape(B, -1), z, cos_xi)
     return w, (dz * w[:, None]).reshape(ad_hoc.shape)
@@ -61,8 +62,8 @@ def fused_sample_q8_ref(slot, ad_hoc, zq, zscale, dzq, dzscale,
 def fused_sample_q4_ref(slot, ad_hoc, zq, zscale, dzq, dzscale,
                         cos_xi: float):
     """int4 nibble-packed ring oracle: unpack the sampled rows' packed
-    bytes (two signed codes per byte, wire-codec layout), dequantize by
-    the per-row scale, then the fp32 composition of
+    bytes (two signed codes per byte, ``workset.pack_nibbles`` layout),
+    dequantize by the per-row scale, then the fp32 composition of
     :func:`fused_sample_ref`.  The pad nibble (odd row widths) decodes to
     an exact zero, so keeping it in the reductions is harmless; the
     cotangent is sliced back to ad_hoc's width."""
@@ -73,9 +74,8 @@ def fused_sample_q4_ref(slot, ad_hoc, zq, zscale, dzq, dzscale,
     Fp = 2 * zq.shape[2]
     if Fp != F:
         a2d = jnp.pad(a2d, ((0, 0), (0, Fp - F)))
-    z = unpack_nibbles(zq[slot]).astype(jnp.float32) * zscale[slot][:, None]
-    dz = unpack_nibbles(dzq[slot]).astype(jnp.float32) \
-        * dzscale[slot][:, None]
+    z = unpack_nibbles(zq[slot]).astype(jnp.float32) * zscale[slot]
+    dz = unpack_nibbles(dzq[slot]).astype(jnp.float32) * dzscale[slot]
     w = cosine_weight_ref(a2d, z, cos_xi)
     return w, (dz * w[:, None])[:, :F].reshape(ad_hoc.shape)
 
@@ -84,15 +84,14 @@ def fused_dequant_q8_ref(slot, zq, zscale):
     """Gather + dequant oracle over the int8 ring (the serving
     decode-cache read): codes * per-row scale at ``slot``.  -> (B, F)
     fp32."""
-    return zq[slot].astype(jnp.float32) * zscale[slot][:, None]
+    return zq[slot].astype(jnp.float32) * zscale[slot]
 
 
 def fused_dequant_q4_ref(slot, zq, zscale, width: int):
     """Gather + unpack + dequant oracle over the int4 nibble-packed ring;
     the pad nibble (odd widths) is sliced off.  -> (B, width) fp32."""
     from ..core.workset import unpack_nibbles
-    out = unpack_nibbles(zq[slot]).astype(jnp.float32) \
-        * zscale[slot][:, None]
+    out = unpack_nibbles(zq[slot]).astype(jnp.float32) * zscale[slot]
     return out[:, :width]
 
 
@@ -101,14 +100,14 @@ def quantize_sr_ref(x, u, levels):
     (the compressed-wire encode hot path).
 
     x, u: (T, L) — T quantization tiles of L values each, u ~ U[0, 1).
-    -> (codes int8 (T, L), scales fp32 (T,)); decode is codes * scales[:,
-    None].  ``floor(x/s + u)`` is unbiased: E[codes * s] == x."""
+    -> (codes int8 (T, L), scales fp32 (T, 1)); decode is codes * scales.
+    ``floor(x/s + u)`` is unbiased: E[codes * s] == x."""
     x = x.astype(jnp.float32)
     u = u.astype(jnp.float32)
     levels = jnp.float32(levels)
-    amax = jnp.max(jnp.abs(x), axis=1)
+    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
     scale = jnp.maximum(amax, EPS) / levels
-    q = jnp.clip(jnp.floor(x / scale[:, None] + u), -levels, levels)
+    q = jnp.clip(jnp.floor(x / scale + u), -levels, levels)
     return q.astype(jnp.int8), scale
 
 

@@ -165,7 +165,7 @@ def workset_pspecs(table, mesh: Mesh, *, data_axes=("data",)):
     data, never the ring axis (a draw reads ONE slot; sharding W would
     turn every gather into a cross-device fetch).  This covers the
     quantized leaves transparently: ``QuantLeaf``/``Quant4Leaf`` codes
-    (W, B, F or packed nibbles) and their (W, B) scales shard B the
+    (W, B, F or packed nibbles) and their (W, B, 1) scales shard B the
     same way, so an int4 ring shards identically to the fp32 ring it
     replaces.  Clock vectors (W,) and scalars replicate."""
     dsize = _axis_size(mesh, tuple(data_axes))

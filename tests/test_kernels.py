@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret=True."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (the kernels run
+in the Pallas interpreter on the CPU)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -129,13 +130,13 @@ import jax  # noqa: E402
 def test_flash_vjp_forward_and_backward(B, S, H, hd, causal, window):
     from repro.kernels.flash_attention_bwd import flash_attention_vjp
     q, k, v = (_arr((B, S, H, hd), "float32") for _ in range(3))
-    o = flash_attention_vjp(q, k, v, causal, window, True)
+    o = flash_attention_vjp(q, k, v, causal, window)
     o_ref = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=2e-4, atol=2e-4)
 
     f_k = lambda *a: jnp.sum(jnp.sin(
-        flash_attention_vjp(*a, causal, window, True)))
+        flash_attention_vjp(*a, causal, window)))
     f_r = lambda *a: jnp.sum(jnp.sin(
         ref.flash_attention_ref(*a, causal=causal, window=window)))
     gk = jax.grad(f_k, argnums=(0, 1, 2))(q, k, v)

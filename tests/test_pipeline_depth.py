@@ -351,8 +351,11 @@ def test_fused_post_scale_traced_staleness_parity(s):
 
 
 def test_traced_staleness_zero_is_identity():
-    """Runtime s = 0 through the dynamic path is bitwise the no-discount
-    result — the drain scan's final pass loses nothing."""
+    """Runtime s = 0 through the dynamic path is the no-discount result —
+    the drain scan's final pass loses nothing.  The jitted dynamic path
+    and the eager static path are separate XLA:CPU programs whose fused
+    row reductions may round differently, so values agree to 2 float32
+    ulps (1 ulp apart on JAX 0.9.0), not bitwise."""
     rng = np.random.default_rng(12)
     a = jnp.asarray(rng.normal(size=(64, 8)), jnp.float32)
     st = jnp.asarray(rng.normal(size=(64, 8)), jnp.float32)
@@ -362,8 +365,11 @@ def test_traced_staleness_zero_is_identity():
     w_d, cot_d = dyn(jnp.int32(0))
     w_0, cot_0 = engine.weighted_cotangent(a, st, dz, 0.5, fused=True,
                                            pipeline_staleness=0)
-    np.testing.assert_array_equal(np.asarray(w_d), np.asarray(w_0))
-    np.testing.assert_array_equal(np.asarray(cot_d), np.asarray(cot_0))
+    ulp2 = 2 * float(np.finfo(np.float32).eps)
+    np.testing.assert_allclose(np.asarray(w_d), np.asarray(w_0),
+                               rtol=ulp2, atol=0)
+    np.testing.assert_allclose(np.asarray(cot_d), np.asarray(cot_0),
+                               rtol=ulp2, atol=0)
 
 
 # --------------------------------------------------------------------------

@@ -179,8 +179,8 @@ def test_fused_sample_q4_matches_oracle(W, B, F):
     if F & 1:   # storage codec invariant: pad nibble holds code 0 (+8)
         zq = (zq & 0x0F) | jnp.uint8(0x80)
         dzq = (dzq & 0x0F) | jnp.uint8(0x80)
-    zs = jnp.abs(_arr((W, B))) + 0.01
-    dzs = jnp.abs(_arr((W, B))) + 0.01
+    zs = jnp.abs(_arr((W, B, 1))) + 0.01
+    dzs = jnp.abs(_arr((W, B, 1))) + 0.01
     for slot in (0, W - 1):
         w, cot = ops.fused_gather_weight_q4(jnp.int32(slot), a, zq, zs,
                                             dzq, dzs, 0.3)
@@ -197,8 +197,8 @@ def test_fused_sample_q8_matches_oracle(W, B, F):
     a = _arr((B, F))
     zq = jnp.asarray(RNG.integers(-127, 128, size=(W, B, F)), jnp.int8)
     dzq = jnp.asarray(RNG.integers(-127, 128, size=(W, B, F)), jnp.int8)
-    zs = jnp.abs(_arr((W, B))) + 0.01
-    dzs = jnp.abs(_arr((W, B))) + 0.01
+    zs = jnp.abs(_arr((W, B, 1))) + 0.01
+    dzs = jnp.abs(_arr((W, B, 1))) + 0.01
     for slot in (0, W - 1):
         w, cot = ops.fused_gather_weight_q8(jnp.int32(slot), a, zq, zs,
                                             dzq, dzs, 0.3)
@@ -234,15 +234,15 @@ def test_fused_sample_all_dead_slot_yields_zero():
     # int8 ring: zero codes AND zero scales (the empty-table state)
     w, cot = ops.fused_gather_weight_q8(
         jnp.int32(0), a, jnp.zeros((W, B, F), jnp.int8),
-        jnp.zeros((W, B), jnp.float32), jnp.zeros((W, B, F), jnp.int8),
-        jnp.zeros((W, B), jnp.float32), 0.5)
+        jnp.zeros((W, B, 1), jnp.float32), jnp.zeros((W, B, F), jnp.int8),
+        jnp.zeros((W, B, 1), jnp.float32), 0.5)
     assert (np.asarray(w) == 0.0).all() and (np.asarray(cot) == 0.0).all()
     # int4 ring: the empty table is 0x88 bytes (code 0 in both nibbles)
     # with zero scales — decodes to exact zeros
     empty = jnp.full((W, B, F // 2), 0x88, jnp.uint8)
     w, cot = ops.fused_gather_weight_q4(
-        jnp.int32(1), a, empty, jnp.zeros((W, B), jnp.float32),
-        empty, jnp.zeros((W, B), jnp.float32), 0.5)
+        jnp.int32(1), a, empty, jnp.zeros((W, B, 1), jnp.float32),
+        empty, jnp.zeros((W, B, 1), jnp.float32), 0.5)
     assert (np.asarray(w) == 0.0).all() and (np.asarray(cot) == 0.0).all()
 
 
@@ -494,8 +494,11 @@ def _loss_b(p, zs, batch):
 def test_party_b_fused_ring_weights_parity(cache_dtype):
     """The label party's dz-side cosine weighting through the fused
     gather→dequant→weight kernel (never materializing the decoded ∇Z
-    list) must agree with the materialize-then-weight reference — bit-
-    exactly on the fp32 ring, to storage precision on int8."""
+    list) must agree with the materialize-then-weight reference — to 2
+    float32 ulps on the fp32 ring (the same operations, but separate
+    XLA:CPU programs whose fused row reductions may round differently;
+    1 ulp apart on JAX 0.9.0; the gradients carry that through one
+    backward pass), to storage precision on int8."""
     ws = _b_workset(cache_dtype=cache_dtype)
     p = {"w": _arr((8,)), "c": jnp.float32(0.1)}
     outs = {}
@@ -505,10 +508,13 @@ def test_party_b_fused_ring_weights_parity(cache_dtype):
         outs[cf] = (g, w)
     (g1, w1), (g0, w0) = outs[True], outs[False]
     if cache_dtype == "float32":
-        np.testing.assert_array_equal(np.asarray(w1), np.asarray(w0))
+        ulp2 = 2 * float(np.finfo(np.float32).eps)
+        np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
+                                   rtol=ulp2, atol=0)
         for a, b in zip(jax.tree_util.tree_leaves(g1),
                         jax.tree_util.tree_leaves(g0)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=4 * ulp2, atol=1e-9)
     else:
         np.testing.assert_allclose(np.asarray(w1), np.asarray(w0),
                                    rtol=1e-5, atol=1e-5)
@@ -559,7 +565,7 @@ def test_workset_pspecs_shard_batch_never_ring():
     specs = workset_pspecs(ws, mesh)
     for k in ("z", "dz"):
         assert specs["buf"][k].q == P(None, "data", None)
-        assert specs["buf"][k].scale == P(None, "data")
+        assert specs["buf"][k].scale == P(None, "data", None)
     for k in ("insert_time", "use_count", "batch_idx", "cursor", "time"):
         assert specs[k] == P()
     # the specs tree must be placeable as-is
