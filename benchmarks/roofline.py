@@ -3,15 +3,14 @@
 1. Aggregates results/dryrun_baseline.jsonl (written by launch.dryrun) into
    the per-(arch x shape x mesh) roofline table used by EXPERIMENTS.md.
 2. Measures the pod-protocol claim: inter-pod ppermute bytes per MODEL
-   UPDATE drop ~(R+1)x with CELU local updates (lowering the 2-pod round
-   with R=0 vs R=5 and parsing the HLO).
+   UPDATE drop ~(R+1)x with CELU local updates (compiling the 2-pod round
+   for two chips of a described TPU v5e with R=0 vs R=5 and parsing the
+   HLO, in the calling process).
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 
 from .common import csv_row
 
@@ -54,47 +53,45 @@ def report_table(paths=None, tag: str = ""):
     return rows
 
 
-_POD_MEASURE = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import jax, jax.numpy as jnp, re, sys
-sys.path.insert(0, {src!r})
-from repro.core.pod_protocol import make_pod_round, init_pod_state
-from repro.optim import adagrad
-from repro.launch.dryrun import collective_bytes
-
-mesh = jax.make_mesh((2,), ("pod",))
-opt = adagrad(0.05)
-for R in (0, 3, 5, 8):
-    params, opt_state, ws = init_pod_state(
-        jax.random.PRNGKey(0), mesh, opt, n_fields=16, vocab=512, batch=4096,
-        W=5, z_dim=256, hidden=256)
-    rnd = make_pod_round(mesh, opt, R=max(R, 1), cos_xi=0.5)
-    x = jax.ShapeDtypeStruct((2, 4096, 16), jnp.int32)
-    y = jax.ShapeDtypeStruct((2, 4096), jnp.float32)
-    lowered = rnd.lower(params, opt_state, ws, x, y)
-    txt = lowered.compile().as_text()
-    coll = collective_bytes(txt)
-    # ppermute bytes per ROUND are constant (Z_A + dZ_A, the paper's 2x4MB
-    # for B=4096 z=256 fp32); CELU funds 1+R updates with them.
-    cp = coll["collective-permute"] if R else coll["collective-permute"]
-    updates = 1 + R
-    print(f"R={{R}} (vanilla)" if R == 0 else f"R={{R}}        ", end=" ")
-    print(f"ppermute_bytes/round={{cp}} updates/round={{updates}} "
-          f"bytes/update={{cp/updates:.0f}}")
-""".format(src=os.path.join(os.path.dirname(__file__), "..", "src"))
-
-
 def pod_collective_accounting():
-    csv_row("# pod-protocol cross-pod bytes (2-device lowering)")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", _POD_MEASURE],
-                       capture_output=True, text=True, env=env, timeout=900)
-    for line in (r.stdout or "").strip().splitlines():
-        csv_row(line)
-    if r.returncode != 0:
-        csv_row("# pod measurement failed:", r.stderr[-400:])
+    """Inter-pod ppermute bytes per model update: the 2-pod round compiled
+    for two chips of a described TPU v5e, in this process (nothing runs,
+    so no device is taken and no child process is started).  Raises when
+    the compile fails."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.pod_protocol import init_pod_state, make_pod_round
+    from repro.launch.dryrun import collective_bytes
+    from repro.optim import adagrad
+
+    csv_row("# pod-protocol cross-pod bytes (2-chip v5e compile)")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:2]), ("pod",))
+    put = NamedSharding(mesh, P("pod"))
+    opt = adagrad(0.05)
+    state = jax.eval_shape(lambda: init_pod_state(
+        jax.random.PRNGKey(0), mesh, opt, n_fields=16, vocab=512,
+        batch=4096, W=5, z_dim=256, hidden=256))
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=put),
+        (*state, jax.ShapeDtypeStruct((2, 4096, 16), jnp.int32),
+         jax.ShapeDtypeStruct((2, 4096), jnp.float32)))
+    for R in (0, 3, 5, 8):
+        rnd = make_pod_round(mesh, opt, R=max(R, 1), cos_xi=0.5)
+        cp = collective_bytes(rnd.lower(*args).compile().as_text())[
+            "collective-permute"]
+        # ppermute bytes per ROUND are constant (Z_A + dZ_A, the paper's
+        # 2x4MB for B=4096 z=256 fp32); CELU funds 1+R updates with them
+        updates = 1 + R
+        csv_row(f"R={R}" + (" (vanilla)" if R == 0 else ""),
+                f"ppermute_bytes/round={cp}", f"updates/round={updates}",
+                f"bytes/update={cp / updates:.0f}")
 
 
 def report_perf_variants():

@@ -34,12 +34,8 @@ from typing import Any, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
 from jax.interpreters import mlir
-
-try:  # jax >= 0.4.34
-    from jax.extend.core import Primitive
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Primitive  # type: ignore[no-redef]
 
 # Identity primitive: abstract-eval and lowering both pass the operand
 # through, so a marked trace computes exactly what the unmarked one does.

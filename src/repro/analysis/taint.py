@@ -43,12 +43,9 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
-from .report import Finding
+from jax.extend.core import Literal
 
-try:
-    from jax.extend.core import Literal
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Literal  # type: ignore[no-redef]
+from .report import Finding
 
 # Collectives that move DATA across a mesh axis (the pod boundary);
 # axis_index only reads coordinates and is always allowed.

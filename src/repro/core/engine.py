@@ -1342,7 +1342,6 @@ def make_pod_round(mesh, opt: Optimizer, *, R: int, cos_xi: float,
     Batch: x (2, B, F) int32 — party p's features on pod p;
            y (2, B) — labels valid on party 1's slot only.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert tower_fwd is not None and top_loss is not None
@@ -1494,9 +1493,9 @@ def make_pod_round(mesh, opt: Optimizer, *, R: int, cos_xi: float,
         return params, opt_state, ws, loss[None]
 
     pp = P(tp.axis)  # every party-stacked leaf shards dim0 over pod
-    fn = shard_map(
+    fn = jax.shard_map(
         exchange_and_local, mesh=mesh,
         in_specs=(pp, pp, pp, pp, pp),
         out_specs=(pp, pp, pp, pp),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)

@@ -81,16 +81,23 @@ _GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _COLL_LINE_RE = re.compile(
     r"^%?[\w.\-]+\s*=\s*(\(?[\w\[\],{}\s]*?\)?)\s*"
     r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(?:-start)?\(")
+    r"(-start)?\(")
+# TPU layouts ({1,0:T(8,128)S(1)}) trail every shape in compiled TPU HLO
+_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
 
 
 def _line_collective(s: str):
     """(op, bytes) for a collective instruction line, else None."""
-    m = _COLL_LINE_RE.match(s)
+    m = _COLL_LINE_RE.match(_LAYOUT_RE.sub("", s))
     if not m:
         return None
-    result_types, op = m.group(1), m.group(2)
-    nbytes = sum(_shape_bytes(sm) for sm in _SHAPE_RE.finditer(result_types))
+    result_types, op, started = m.group(1), m.group(2), m.group(3)
+    shapes = list(_SHAPE_RE.finditer(result_types))
+    if started and result_types.startswith("(") and shapes:
+        # async start (TPU): a (operand, result, context...) tuple — the
+        # first element IS the operand
+        return op, _shape_bytes(shapes[0])
+    nbytes = sum(_shape_bytes(sm) for sm in shapes)
     gm = _GROUPS_RE.search(s)
     g = int(gm.group(2)) if gm else 1
     if op == "all-gather" and g:

@@ -141,4 +141,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable
+    enable()
     main()

@@ -5,8 +5,11 @@ Two modes:
     the synthetic vertically-partitioned stream with the selected protocol
     (vanilla | fedbcd | celu) and reports AUC + communication accounting
     (rounds, bytes, simulated-WAN seconds).
-  * LLM backbones: --arch <assigned-id> trains a REDUCED variant on CPU for
-    --steps rounds (the full configs are exercised by the dry-run only).
+  * LLM backbones: --arch <assigned-id> trains the split LLM for --rounds
+    rounds (--reduced shrinks the widths for a quick CPU run).
+
+At pipeline depth 0 the round program is compiled before the first round
+and its compile time printed as set-up.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch wdl-criteo \
@@ -111,6 +114,18 @@ def make_opt(args):
     return make_optimizer(args.optimizer, args.lr, **kw)
 
 
+def _compile_round(rnd, state, batches_a, batch_b):
+    """Compile the jitted round for the first batch's shapes before the
+    loop, so compilation is set-up time, not part of round 1.  -> (the
+    compiled round, compile seconds); the caller may inspect the
+    compiled program (``.as_text()``)."""
+    t0 = time.perf_counter()
+    compiled = rnd.lower(state, batches_a, batch_b, 0).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"[compile] round program in {compile_s:.1f}s", flush=True)
+    return compiled, compile_s
+
+
 def train_dlrm(args) -> Dict[str, Any]:
     cfg: DLRMConfig = get_config(args.arch)
     if args.small:
@@ -165,8 +180,10 @@ def train_dlrm(args) -> Dict[str, Any]:
                          local_steps=n_local, transport=transport)
         rs = pe.init(state)
     else:
-        rnd = engine.make_round(etask, opt, celu_cfg, local_steps=n_local,
-                                transport=transport, donate=True)
+        rnd, compile_s = _compile_round(
+            engine.make_round(etask, opt, celu_cfg, local_steps=n_local,
+                              transport=transport, donate=True),
+            state, [_as_jax(ba0)], _as_jax(bb0))
     start_round = 0
     if args.resume:
         n_pend = ckpt.peek_pending_len(args.resume)
@@ -262,6 +279,8 @@ def train_dlrm(args) -> Dict[str, Any]:
         "pipeline_depth": depth, "compute_wall_s": wall,
         "history": history,
     }
+    if not engineful:
+        out.update(compile_s=compile_s, round=rnd)
     pipe_note = (f" (sequential would be {seq_s:.1f}s -> "
                  f"{seq_s / comm_s:.2f}x overlap win)") if depth else ""
     auc_note = "n/a" if out["final_auc"] is None \
@@ -307,8 +326,10 @@ def train_llm(args) -> Dict[str, Any]:
                                   local_steps=n_local)
         rs = pe.init(state)
     else:
-        rnd = engine.make_round(etask, opt, celu_cfg, local_steps=n_local,
-                                donate=True)
+        rnd, compile_s = _compile_round(
+            engine.make_round(etask, opt, celu_cfg, local_steps=n_local,
+                              donate=True),
+            state, [_as_jax(ba0)], _as_jax(bb0))
     it = synth.token_batches(data, B, seed=args.seed)
     losses = []
     for i in range(args.rounds):
@@ -326,7 +347,10 @@ def train_llm(args) -> Dict[str, Any]:
                                    # drained model for future extension
     print(f"[done] {args.arch} {args.protocol}: "
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
-    return {"arch": args.arch, "losses": losses}
+    out = {"arch": args.arch, "losses": losses}
+    if not depth:
+        out.update(compile_s=compile_s, round=rnd)
+    return out
 
 
 def main(argv=None):
@@ -410,8 +434,7 @@ def main(argv=None):
                     choices=("float32", "bfloat16", "int8"),
                     help="at-rest precision of the AdaGrad accumulator "
                          "(int8 = sqrt-space codes + fp32 per-row master "
-                         "scales through the fused requant kernel, ~4x "
-                         "smaller; optim/quantized.py)")
+                         "scales, ~4x smaller; optim/quantized.py)")
     ap.add_argument("--remat", default=True,
                     action=argparse.BooleanOptionalAction,
                     help="activation-checkpoint the LLM tower scans "
@@ -432,4 +455,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from .compile_cache import enable
+    enable()
     main()

@@ -293,3 +293,21 @@ def test_ring_clear_lane_decodes_to_zero():
                 jnp.int32(slot)))
             np.testing.assert_array_equal(out[3], np.zeros(128, np.float32))
             assert np.any(out[2] != 0)     # neighbours untouched
+
+
+def test_fused_dequant_q4_matches_ref_at_model_width():
+    """The int4 decode read at smollm's d=960: 480 packed bytes are three
+    full 128-byte lane groups plus a short one."""
+    d = 960
+    z = jnp.asarray(np.random.default_rng(5).normal(size=(4, 8, d)),
+                    jnp.float32)
+    ws = WS.workset_init(4, {"z": z[0]}, cache_dtype="int4")
+    for t in range(4):
+        ws = WS.workset_insert(ws, {"z": z[t]}, t)
+    buf = ws["buf"]["z"]
+    for slot in range(4):
+        got = kops.fused_gather_dequant_q4(jnp.int32(slot), buf.q, buf.scale,
+                                           d)
+        want = kref.fused_dequant_q4_ref(jnp.int32(slot), buf.q, buf.scale,
+                                         d)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

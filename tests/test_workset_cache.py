@@ -112,8 +112,9 @@ def test_cache_footprint_ratio():
 
 def test_int4_pack_roundtrip_odd_widths():
     """pack→unpack is the identity on codes in [-7, 7], with odd widths
-    padded by one zero code (the pad nibble decodes to an exact 0)."""
-    for B, F in ((4, 8), (3, 7), (5, 33), (2, 1)):
+    padded by one zero code (the pad nibble decodes to an exact 0), over
+    one short lane group and over full 256-code groups plus a rest."""
+    for B, F in ((4, 8), (3, 7), (5, 33), (2, 1), (3, 600), (2, 1025)):
         q = jnp.asarray(RNG.integers(-7, 8, size=(B, F)), jnp.int8)
         qp = jnp.pad(q, ((0, 0), (0, F & 1))) if F & 1 else q
         packed = pack_nibbles(qp)
@@ -207,6 +208,81 @@ def test_fused_sample_q8_matches_oracle(W, B, F):
                                    rtol=3e-7, atol=3e-7)
         np.testing.assert_allclose(np.asarray(cot), np.asarray(cot_r),
                                    rtol=3e-6, atol=3e-6)
+
+
+def _q8_ring(W, B, F):
+    zq = jnp.asarray(RNG.integers(-127, 128, size=(W, B, F)), jnp.int8)
+    return zq, jnp.abs(_arr((W, B, 1))) + 0.01
+
+
+@pytest.mark.parametrize("kind", ["float32", "int8"])
+def test_fused_sample_w5_bit_exact(kind):
+    """At the paper's ring depth (W=5) a one-tile row runs the oracle's
+    float32 operations on the same blocks: weights and cotangent are
+    bit-identical to ``kernels.ref`` in the interpreter."""
+    W, B, F = 5, 128, 256
+    a = _arr((B, F))
+    for slot in (0, 4):
+        if kind == "float32":
+            z, dz = _arr((W, B, F)), _arr((W, B, F))
+            got = ops.fused_gather_weight(jnp.int32(slot), a, z, dz, 0.5)
+            want = ref.fused_sample_ref(slot, a, z, dz, 0.5)
+        else:
+            (zq, zs), (dzq, dzs) = _q8_ring(W, B, F), _q8_ring(W, B, F)
+            got = ops.fused_gather_weight_q8(jnp.int32(slot), a, zq, zs,
+                                             dzq, dzs, 0.5)
+            want = ref.fused_sample_q8_ref(slot, a, zq, zs, dzq, dzs, 0.5)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("kind", ["float32", "int8", "int4"])
+def test_fused_sample_feature_tiles_match_oracle(kind):
+    """A split-LLM-sized row (B=8, F=32768) is cut into two feature tiles:
+    the row sums accumulate across tiles, which reassociates the float32
+    reduction (weights agree to 1e-6 relative), and the cotangent is
+    written tile by tile in the second phase."""
+    from repro.kernels.cosine_weight import feature_tile
+    W, B, F = 3, 8, 32768
+    assert F // feature_tile(F, B) == 2
+    a = _arr((B, F))
+    if kind == "float32":
+        z, dz = _arr((W, B, F)), _arr((W, B, F))
+        w, cot = ops.fused_gather_weight(jnp.int32(1), a, z, dz, -1.0)
+        w_r, cot_r = ref.fused_sample_ref(1, a, z, dz, -1.0)
+    elif kind == "int8":
+        (zq, zs), (dzq, dzs) = _q8_ring(W, B, F), _q8_ring(W, B, F)
+        w, cot = ops.fused_gather_weight_q8(jnp.int32(1), a, zq, zs, dzq,
+                                            dzs, -1.0)
+        w_r, cot_r = ref.fused_sample_q8_ref(1, a, zq, zs, dzq, dzs, -1.0)
+    else:
+        P = F // 2
+        zq = jnp.asarray(RNG.integers(0, 256, size=(W, B, P)), jnp.uint8)
+        dzq = jnp.asarray(RNG.integers(0, 256, size=(W, B, P)), jnp.uint8)
+        zs, dzs = (jnp.abs(_arr((W, B, 1))) + 0.01 for _ in range(2))
+        w, cot = ops.fused_gather_weight_q4(jnp.int32(1), a, zq, zs, dzq,
+                                            dzs, -1.0)
+        w_r, cot_r = ref.fused_sample_q4_ref(1, a, zq, zs, dzq, dzs, -1.0)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(w_r), rtol=1e-6,
+                               atol=1e-7)
+    np.testing.assert_allclose(np.asarray(cot), np.asarray(cot_r),
+                               rtol=1e-6, atol=1e-7)
+
+
+def test_quantizer_feature_tiles_bit_exact():
+    """The SR quantizer over a row cut into feature tiles: the absmax is
+    exact in any order, so codes and scales stay bit-identical to the
+    oracle."""
+    from repro.kernels.cosine_weight import feature_tile
+    B, F = 8, 32768
+    assert F // feature_tile(F, B) == 2
+    x = _arr((B, F))
+    u = jax.random.uniform(jax.random.PRNGKey(3), (B, F), jnp.float32)
+    for levels in (127, 7):
+        got = ops.quantize_stochastic(x, u, levels)
+        want = ref.quantize_sr_ref(x, u, levels)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_fused_sample_rank3_statistics():
