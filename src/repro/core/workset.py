@@ -97,8 +97,9 @@ class QuantLeaf:
 
     ``q`` holds signed int8 codes of the leaf flattened to (B, F) rows
     (table level: (W, B, F)), ``scale`` one fp32 absmax scale per row as
-    a column ((B, 1) / (W, B, 1): the layout the fused kernels read).  ``shape``/``dtype`` remember the original per-entry
-    leaf so :meth:`dequant` can restore it."""
+    a column ((B, 1) / (W, B, 1): the layout the fused kernels read).
+    ``shape``/``dtype`` remember the original per-entry leaf so
+    :meth:`dequant` can restore it."""
 
     __slots__ = ("q", "scale", "shape", "dtype")
 
@@ -186,8 +187,8 @@ class Quant4Leaf:
     per byte — of the leaf flattened to (B, F) rows and F padded to even
     (entry level (B, ceil(F/2)); table level (W, B, ceil(F/2))).
     ``scale`` is one fp32 absmax scale per row ((B, 1) / (W, B, 1)),
-    exactly like :class:`QuantLeaf`.  The pad nibble stores code 0 so it decodes
-    to an exact zero; :meth:`dequant` slices it away."""
+    exactly like :class:`QuantLeaf`.  The pad nibble stores code 0 so it
+    decodes to an exact zero; :meth:`dequant` slices it away."""
 
     __slots__ = ("q", "scale", "shape", "dtype")
 
@@ -251,8 +252,8 @@ def _row_shape(a) -> Tuple[int, int]:
 
 
 def _quantize_rows(rng, x2d, levels: int = 127):
-    """(B, F) fp32 -> (codes int8 (B, F), fp32 row scales (B, 1)); the fused
-    Pallas SR quantizer when the grid can tile B, its bit-identical jnp
+    """(B, F) fp32 -> (codes int8 (B, F), fp32 row scales (B, 1)); the
+    fused Pallas SR quantizer when the grid can tile B, its bit-identical jnp
     oracle otherwise.  ``levels`` is the max code magnitude (127 = int8 at
     rest, 7 = int4 at rest — the codes come back int8 either way; the int4
     caller nibble-packs them)."""

@@ -29,33 +29,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_call
+from . import feature_tile, pallas_call, smem_scalar
 
 EPS = 1e-12
 BLOCK_B = 128
-BLOCK_ELEMS = 128 * 1024     # fp32 elements per (rows, feature-tile) block
-LANES = 128
-
-
-def feature_tile(F: int, rows: int, width: int = 1) -> int:
-    """Feature-tile length for a (rows, F) operand.
-
-    ``width`` is how many values one stored element expands to in VMEM
-    (2 for nibble-packed int4).  The whole row when ``rows * F * width``
-    fits one block (or F cannot be cut on lane boundaries); otherwise the
-    largest multiple of 128 lanes that divides F and fits."""
-    limit = max(LANES, BLOCK_ELEMS // (rows * width))
-    if F <= limit or F % LANES:
-        return F
-    t = limit // LANES * LANES
-    while F % t:
-        t -= LANES
-    return t
-
-
-def smem_scalar():
-    """BlockSpec of a (1,) scalar operand held in SMEM."""
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def row_sums_scratch(rows: int):

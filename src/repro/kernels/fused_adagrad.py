@@ -32,8 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import pallas_call
-from .cosine_weight import smem_scalar
+from . import pallas_call, smem_scalar
 
 BLOCK = 1024
 ROWS = 8

@@ -19,7 +19,7 @@ Layout decisions for TPU:
     copy exists;
   * rows (instances) on the sublane axis, the flattened feature dim on the
     lane axis, cut into lane-aligned feature tiles
-    (``cosine_weight.feature_tile``).  The grid is (row blocks, phase,
+    (``kernels.feature_tile``).  The grid is (row blocks, phase,
     feature tiles): phase 0 accumulates the three row sums over the tiles
     of a ∗ z, phase 1 walks the ∇Z tiles and writes the weighted
     cotangent once the row's weight is known.  Index maps hold the block
@@ -45,9 +45,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_call
-from .cosine_weight import (BLOCK_B, LANES, accumulate, feature_tile,
-                            row_sums_scratch, row_weights, smem_scalar)
+from . import LANES, feature_tile, pallas_call, smem_scalar
+from .cosine_weight import (BLOCK_B, accumulate, row_sums_scratch,
+                            row_weights)
 
 
 def _decode_f32(z_ref):

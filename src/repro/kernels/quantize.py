@@ -12,7 +12,7 @@ Layout decisions for TPU:
   * quantization rows on the sublane axis, the L values of a row on the
     lane axis — the absmax is a lane reduction, natively supported;
   * a long row (a split-LLM cut row, L = S * d) is cut into lane-aligned
-    feature tiles (``cosine_weight.feature_tile``): the grid is (row
+    feature tiles (``kernels.feature_tile``): the grid is (row
     blocks, phase, tiles), phase 0 folds each tile's absmax into a VMEM
     scratch, phase 1 encodes the tiles against the finished row scale.
     A row that is one tile runs both phases in one step.  The max is
@@ -36,8 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_call
-from .cosine_weight import feature_tile, smem_scalar
+from . import feature_tile, pallas_call, smem_scalar
 
 EPS = 1e-12
 BLOCK_T = 128

@@ -125,6 +125,9 @@ for i in range(3):
     params_p, opt_state_p, ws_p, loss_p = rnd_p(params_p, opt_state_p, ws_p,
                                                 jnp.asarray(x),
                                                 jnp.asarray(y))
+# the pod-sharded (2,) losses come back to the host whole: indexing them
+# on device would be a gather across the explicitly sharded pod axis
+loss, loss_p = np.asarray(loss), np.asarray(loss_p)
 assert np.isfinite(float(loss[1])), loss
 assert np.isfinite(float(loss_p[1])), loss_p
 print("POD_OK", float(loss[1]), float(loss_p[1]))

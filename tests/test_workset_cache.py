@@ -242,7 +242,7 @@ def test_fused_sample_feature_tiles_match_oracle(kind):
     the row sums accumulate across tiles, which reassociates the float32
     reduction (weights agree to 1e-6 relative), and the cotangent is
     written tile by tile in the second phase."""
-    from repro.kernels.cosine_weight import feature_tile
+    from repro.kernels import feature_tile
     W, B, F = 3, 8, 32768
     assert F // feature_tile(F, B) == 2
     a = _arr((B, F))
@@ -273,7 +273,7 @@ def test_quantizer_feature_tiles_bit_exact():
     """The SR quantizer over a row cut into feature tiles: the absmax is
     exact in any order, so codes and scales stay bit-identical to the
     oracle."""
-    from repro.kernels.cosine_weight import feature_tile
+    from repro.kernels import feature_tile
     B, F = 8, 32768
     assert F // feature_tile(F, B) == 2
     x = _arr((B, F))
