@@ -14,7 +14,7 @@ For each phase the training entry point compiles the round program before
 the first round; this script reads that compiled program and requires a
 Mosaic kernel (``tpu_custom_call``) for every kernel the phase must use,
 so a silent fall back to a jnp reference fails the run.  Losses must be
-finite, and for wdl-criteo the last below the first.
+finite, and the last below the first.
 
 ``--chips 4`` runs only the two-party pod round (``make_pod_round``):
 Party A on chip 0, Party B on chip 1, pipeline depths 0 and 1, compared
@@ -34,6 +34,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import os
 import re
 import sys
@@ -89,7 +90,7 @@ def _check(cond: bool, what: str):
         raise SystemExit(f"chip_smoke: FAILED: {what}")
 
 
-def _train_phase(name, argv, kernels, *, rehearse, must_descend):
+def _train_phase(name, argv, kernels, *, rehearse):
     from repro.launch import train
     t0 = time.perf_counter()
     out = train.main(argv)
@@ -104,13 +105,11 @@ def _train_phase(name, argv, kernels, *, rehearse, must_descend):
         "phase": name, "platform": dev["platform"], "kind": dev["kind"],
         "compile_s": out["compile_s"], "wall_s": wall, "losses": losses,
         "kernels": found, "peak_bytes_in_use": _peak_bytes()}), flush=True)
-    _check(len(losses) > 0 and all(l == l and abs(l) != float("inf")
-                                   for l in losses),
+    _check(len(losses) > 0 and all(map(math.isfinite, losses)),
            f"{name}: non-finite losses {losses}")
-    if must_descend:
+    if not rehearse:        # tiny rehearsal runs are too short to fall
         _check(losses[-1] < losses[0],
                f"{name}: loss did not fall ({losses[0]} -> {losses[-1]})")
-    if not rehearse:
         missing = [k for k in kernels if k not in found]
         _check(not missing, f"{name}: no Mosaic call for {missing} in the "
                f"compiled round (found {found})")
@@ -126,20 +125,18 @@ def one_chip(rehearse: bool):
         wdl += ["--rounds", "12", "--batch-size", "4096",
                 "--n-train", "32768", "--n-test", "8192", "--lr", "0.001"]
     _train_phase("wdl_fp32", wdl + ["--cache-dtype", "float32"],
-                 WDL_FP32_KERNELS, rehearse=rehearse,
-                 must_descend=not rehearse)
+                 WDL_FP32_KERNELS, rehearse=rehearse)
     _train_phase("wdl_int8", wdl + ["--cache-dtype", "int8"],
-                 WDL_INT8_KERNELS, rehearse=rehearse,
-                 must_descend=not rehearse)
+                 WDL_INT8_KERNELS, rehearse=rehearse)
     llm = ["--arch", "smollm-360m", "--protocol", "celu", "--R", "5",
            "--W", "5", "--pipeline-depth", "0", "--cache-dtype", "int8",
-           "--opt-state-dtype", "int8", "--seed", "0", "--rounds", "3"]
+           "--opt-state-dtype", "int8", "--seed", "0", "--rounds", "3",
+           "--lr", "0.001"]
     if rehearse:
         llm += ["--reduced", "--batch-size", "2", "--seq-len", "16"]
     else:
         llm += ["--batch-size", "8", "--seq-len", "512"]
-    _train_phase("smollm_int8", llm, LLM_INT8_KERNELS, rehearse=rehearse,
-                 must_descend=False)
+    _train_phase("smollm_int8", llm, LLM_INT8_KERNELS, rehearse=rehearse)
 
 
 def _pod_inputs(cfg, seed):
@@ -232,7 +229,8 @@ def four_chips(rehearse: bool):
             "max_abs_diff": diff, "atol": POD_LOSS_ATOL,
             "collective_permute": "collective-permute" in text,
             "peak_bytes_in_use": _peak_bytes()}), flush=True)
-        _check(all(l == l for l in got), f"depth {depth}: NaN loss {got}")
+        _check(all(map(math.isfinite, got)),
+               f"depth {depth}: non-finite losses {got}")
         _check(diff <= POD_LOSS_ATOL,
                f"depth {depth}: chip and host-CPU losses differ by {diff}")
         _check("collective-permute" in text,
