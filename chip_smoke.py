@@ -14,7 +14,9 @@ For each phase the training entry point compiles the round program before
 the first round; this script reads that compiled program and requires a
 Mosaic kernel (``tpu_custom_call``) for every kernel the phase must use,
 so a silent fall back to a jnp reference fails the run.  Losses must be
-finite, and the last below the first.
+finite, and for wdl-criteo the last below the first (three rounds of the
+split LLM on the synthetic bigram stream stay near ln(vocab) and need
+not fall).
 
 ``--chips 4`` runs only the two-party pod round (``make_pod_round``):
 Party A on chip 0, Party B on chip 1, pipeline depths 0 and 1, compared
@@ -90,7 +92,7 @@ def _check(cond: bool, what: str):
         raise SystemExit(f"chip_smoke: FAILED: {what}")
 
 
-def _train_phase(name, argv, kernels, *, rehearse):
+def _train_phase(name, argv, kernels, *, rehearse, must_fall):
     from repro.launch import train
     t0 = time.perf_counter()
     out = train.main(argv)
@@ -107,9 +109,10 @@ def _train_phase(name, argv, kernels, *, rehearse):
         "kernels": found, "peak_bytes_in_use": _peak_bytes()}), flush=True)
     _check(len(losses) > 0 and all(map(math.isfinite, losses)),
            f"{name}: non-finite losses {losses}")
-    if not rehearse:        # tiny rehearsal runs are too short to fall
+    if must_fall:
         _check(losses[-1] < losses[0],
                f"{name}: loss did not fall ({losses[0]} -> {losses[-1]})")
+    if not rehearse:
         missing = [k for k in kernels if k not in found]
         _check(not missing, f"{name}: no Mosaic call for {missing} in the "
                f"compiled round (found {found})")
@@ -124,10 +127,11 @@ def one_chip(rehearse: bool):
     else:
         wdl += ["--rounds", "12", "--batch-size", "4096",
                 "--n-train", "32768", "--n-test", "8192", "--lr", "0.001"]
+    # tiny rehearsal runs are too short to fall
     _train_phase("wdl_fp32", wdl + ["--cache-dtype", "float32"],
-                 WDL_FP32_KERNELS, rehearse=rehearse)
+                 WDL_FP32_KERNELS, rehearse=rehearse, must_fall=not rehearse)
     _train_phase("wdl_int8", wdl + ["--cache-dtype", "int8"],
-                 WDL_INT8_KERNELS, rehearse=rehearse)
+                 WDL_INT8_KERNELS, rehearse=rehearse, must_fall=not rehearse)
     llm = ["--arch", "smollm-360m", "--protocol", "celu", "--R", "5",
            "--W", "5", "--pipeline-depth", "0", "--cache-dtype", "int8",
            "--opt-state-dtype", "int8", "--seed", "0", "--rounds", "3",
@@ -136,7 +140,8 @@ def one_chip(rehearse: bool):
         llm += ["--reduced", "--batch-size", "2", "--seq-len", "16"]
     else:
         llm += ["--batch-size", "8", "--seq-len", "512"]
-    _train_phase("smollm_int8", llm, LLM_INT8_KERNELS, rehearse=rehearse)
+    _train_phase("smollm_int8", llm, LLM_INT8_KERNELS, rehearse=rehearse,
+                 must_fall=False)
 
 
 def _pod_inputs(cfg, seed):
