@@ -42,6 +42,8 @@ def main(argv=None):
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--faults", default="",
                     help="comma-separated faults to plant in the program")
+    ap.add_argument("--no-half-batch", action="store_true",
+                    help="read the control alone on the control seeds")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     cell = harness.Cell(args.workload, rehearse=args.rehearse)
@@ -62,8 +64,9 @@ def main(argv=None):
         if seed in control:
             out["control"] = run.numbers(ref=ref,
                                          prog=run.reference(control=True))
-            out["half_batch"] = run.numbers(
-                ref=ref, prog=run.reference(rows=cell.job["batch"] // 2))
+            if not args.no_half_batch:
+                out["half_batch"] = run.numbers(
+                    ref=ref, prog=run.reference(rows=cell.job["batch"] // 2))
             for fault in filter(None, args.faults.split(",")):
                 bad = cell.driver.Run(cell, seed, fault=fault)
                 bad.release()

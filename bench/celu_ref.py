@@ -27,6 +27,7 @@ the precision ``Policy`` given (``float32`` for the reference itself).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable, Dict, List
 
@@ -63,8 +64,11 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
     """Run ``len(batches)`` rounds from ``params`` ({"a", "b"}, float32).
 
     ``rows`` > 0 keeps only the first ``rows`` rows of every batch (the
-    half-batch fault).  -> {"losses": [...], "accum1": {"a": tree, "b":
-    tree} (AdaGrad accumulators after round 1), "params": final}."""
+    half-batch fault).  -> {"losses": [...], "grad1": the root of each
+    AdaGrad accumulator's sum after round 1, party A's leaves then B's,
+    "z1", "dz1": round 1's exchanged Z and dZ, "params": final}.  Each step updates its parameters and accumulators
+    in place (donated), so a table-sized reference fits beside the
+    caller's initial weights."""
     W, R, lr = job["W"], job["R"], job["lr"]
     celu = job["protocol"] == "celu"
     n_local = R if celu else 0
@@ -75,7 +79,7 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
         return jax.tree_util.tree_map(lambda x: x[:rows], tree) if rows \
             else tree
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
     def exchange(pa, pb, acc_a, acc_b, ba, bb):
         z, vjp_a = jax.vjp(lambda p: forward_a(p, ba, P), pa)
         loss, (gb, dz) = jax.value_and_grad(
@@ -87,7 +91,7 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
         return loss, pa, pb, acc_a, acc_b, z.astype(jnp.float32), \
             dz.astype(jnp.float32)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def local_a(pa, acc, ba, z, dz):
         z_new, vjp_a = jax.vjp(lambda p: forward_a(p, ba, P), pa)
         w = _cos_weights(z_new, z, cos_xi)
@@ -95,7 +99,7 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
         (g,) = vjp_a(cot.astype(z_new.dtype))
         return opt(pa, acc, g)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def local_b(pb, acc, bb, z, dz):
         dz_new = jax.grad(lambda z: jnp.mean(loss_b(pb, z, bb, P)))(z)
         w = _cos_weights(dz_new, dz, cos_xi)
@@ -104,10 +108,11 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
 
     zeros = lambda t: jax.tree_util.tree_map(  # noqa: E731
         lambda x: jnp.zeros(x.shape, jnp.float32), t)
-    pa, pb = params["a"], params["b"]
+    copy = lambda t: jax.tree_util.tree_map(jnp.copy, t)  # noqa: E731
+    pa, pb = copy(params["a"]), copy(params["b"])
     acc_a, acc_b = zeros(pa), zeros(pb)
     ring = [None] * W            # (z, dz, batch_a, batch_b, round, uses)
-    cursor, losses, accum1 = 0, [], None
+    cursor, losses, grad1 = 0, [], None
     for t, (_, ba, bb) in enumerate(batches):
         ba, bb = cut(ba), cut(bb)
         loss, pa, pb, acc_a, acc_b, z, dz = exchange(pa, pb, acc_a, acc_b,
@@ -124,6 +129,13 @@ def run(forward_a: Callable, loss_b: Callable, params: Dict, batches: List,
             pa, acc_a = local_a(pa, acc_a, e[2], e[0], e[1])
             pb, acc_b = local_b(pb, acc_b, e[3], e[0], e[1])
         if t == 0:
-            accum1 = {"a": acc_a, "b": acc_b}
-    return {"losses": losses, "accum1": accum1,
+            grad1 = _root_sums(jax.tree_util.tree_leaves(acc_a)
+                               + jax.tree_util.tree_leaves(acc_b))
+            z1, dz1 = z, dz
+    return {"losses": losses, "grad1": grad1, "z1": z1, "dz1": dz1,
             "params": {"a": pa, "b": pb}}
+
+
+@jax.jit
+def _root_sums(leaves):
+    return [jnp.sqrt(jnp.sum(x)) for x in leaves]

@@ -1,6 +1,6 @@
 """The comparison that decides ``correct`` for a training cell.
 
-Three numbers, each the worst case over its parts, each compared with
+Numbers, each a worst or a median case over its parts, compared with
 the same number taken from the plain reference that followed the same
 first steps from the same weights and rows:
 
@@ -12,6 +12,16 @@ first steps from the same weights and rows:
   squared norms of the step's updates); the gap between the program's
   and the reference's norm, over the larger of the reference leaf's
   norm and the median leaf's, worst leaf;
+* ``grad_gap_median``: the same gaps, the median leaf's rather than the
+  worst: the worst is one small leaf whose gradient is a mostly
+  cancelling sum over the batch (a bias), which rounding moves by its
+  nature, where the median is steady from seed to seed;
+* ``z_gap``, ``dz_gap``: round 1's exchanged cut activation Z and its
+  derivative dZ, as party A's workset ring keeps them (a ring stored as
+  it is, float32): |program - reference| / |reference| over all rows and
+  values.  Round 1's exchange runs before any update, so these read the
+  exchange's own arithmetic, where the later numbers also carry what
+  AdaGrad's first, sign-like step does to near-zero gradients;
 * ``update_gap``: the same for the norm of each leaf's change from its
   initial value after the last first step.  Leaves whose reference
   gradient norm is under a thousandth of the median leaf's are left out
@@ -81,20 +91,44 @@ def gaps(prog: Dict, ref: Dict, names: Sequence[str]) -> Dict:
         np.asarray(ref["grad"], np.float64)
     g = np.abs(gp - gr) / np.maximum(gr, np.median(gr))
     out["grad_gap"] = float(np.max(g))
-    out["grad_worst"] = names[int(np.argmax(g))]
+    out["grad_gap_median"] = float(np.median(g))
+    w = int(np.argmax(g))
+    out["grad_worst"] = names[w]
+    out["grad_worst_norms"] = _norms(gp[w], gr[w], np.median(gr))
     keep = gr >= IGNORE_BELOW * np.median(gr)
     dp, dr = np.asarray(prog["change"], np.float64)[keep], \
         np.asarray(ref["change"], np.float64)[keep]
     d = np.abs(dp - dr) / np.maximum(dr, np.median(dr))
     out["update_gap"] = float(np.max(d))
-    out["update_worst"] = [n for n, k in zip(names, keep) if k][
-        int(np.argmax(d))]
+    w = int(np.argmax(d))
+    out["update_worst"] = [n for n, k in zip(names, keep) if k][w]
+    out["update_worst_norms"] = _norms(dp[w], dr[w], np.median(dr))
     out["update_kept"] = f"{int(keep.sum())}/{len(keep)}"
+    for k in ("z", "dz"):
+        if k in prog and k in ref:
+            out[k + "_gap"] = cut_gap(prog[k], ref[k])
     out["ignored"] = [n for n, k in zip(names, keep) if not k]
     return out
 
 
-COMPARED = ("loss_gap", "grad_gap", "update_gap")
+def cut_gap(prog, ref) -> float:
+    """|prog - ref| / |ref| over a whole cut statistic (rows x values);
+    1 where the program holds other rows than the reference."""
+    prog, ref = np.asarray(prog, np.float64), np.asarray(ref, np.float64)
+    if prog.shape != ref.shape:
+        return 1.0
+    return float(np.linalg.norm(prog - ref) / np.linalg.norm(ref))
+
+
+def _norms(prog, ref, median) -> Dict:
+    """The worst leaf's two norms and the reference's median leaf norm,
+    which show how a gap came about (a leaf of 0 reads 1)."""
+    return {"program": float(prog), "reference": float(ref),
+            "reference_median": float(median)}
+
+
+COMPARED = ("loss_gap", "grad_gap", "grad_gap_median", "update_gap",
+            "z_gap", "dz_gap")
 
 
 def judge(numbers: Dict, limits: Dict) -> Tuple[Dict, bool]:

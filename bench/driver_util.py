@@ -3,6 +3,8 @@ the host spans of a traced step."""
 from __future__ import annotations
 
 import contextlib
+import sys
+import time
 
 import check
 
@@ -40,3 +42,21 @@ def span(name):
     """A host span in the profiler's trace (``jax.profiler``)."""
     import jax
     return jax.profiler.TraceAnnotation(name)
+
+
+class Phases:
+    """Seconds of each part of a set-up, printed on standard error, so
+    that a set-up that varies shows where."""
+
+    def __init__(self):
+        self.t, self.parts = time.perf_counter(), {}
+
+    def mark(self, name: str):
+        now = time.perf_counter()
+        self.parts[name] = now - self.t
+        self.t = now
+
+    def say(self):
+        print("[bench] set-up parts: " + ", ".join(
+            f"{k} {v:.3f} s" for k, v in self.parts.items()),
+            file=sys.stderr, flush=True)

@@ -23,7 +23,7 @@ import check
 import celu_ref
 import precision
 import traffic as traffic_gen
-from driver_util import no_span, same_layout, seed_key, span
+from driver_util import Phases, no_span, same_layout, seed_key, span
 
 FIRST_STEPS = 3
 
@@ -31,7 +31,6 @@ FIRST_STEPS = 3
 class Run:
     def __init__(self, cell, seed: int, fault: str = ""):
         import jax
-        import jax.numpy as jnp
 
         from repro.configs.base import CELUConfig
         from repro.core import engine
@@ -46,12 +45,14 @@ class Run:
         self.tokens_per_step = self.rows_per_step * job["seq_len"] \
             if "seq_len" in job else None
         self.devices = jax.devices()[:1]
+        phases = Phases()
 
         task, shapes = cell.family.program(cell.cfg)
         self._make = jax.jit(lambda k: cell.family.make_weights(cell.cfg, k))
         weights = self._make(seed_key(seed))
         same_layout(weights, shapes)
-        p0 = jax.tree_util.tree_map(jnp.copy, weights)
+        jax.block_until_ready(weights)
+        phases.mark("weights")
 
         base = CELUConfig(R=job["R"], W=job["W"], xi_degrees=job["xi"],
                           cache_dtype=job["cache_dtype"])
@@ -62,6 +63,7 @@ class Run:
 
         self.stream = traffic_gen.stream(seed, cell.data_spec())
         self.first = [next(self.stream) for _ in range(FIRST_STEPS)]
+        phases.mark("data")
         etask = engine.lift_two_party(task)
         tp = engine.make_transport(celu)
         a0, b0 = self._put(self.first[0])
@@ -71,6 +73,7 @@ class Run:
         fn = engine.make_round(etask, opt, celu, local_steps=n_local,
                                transport=tp, donate=donate)
         fn = self.compiled = fn.lower(state, a0, b0, 0).compile()
+        phases.mark("compile")
         if fault == "frozen":           # a step that returns its state
             self.fn = lambda s, a, b, i: (s, fn(s, a, b, i)[1])
         else:
@@ -85,13 +88,21 @@ class Run:
             losses.append(m["loss"])
             if t == 0:
                 grad = jax.block_until_ready(self._grad_norms(self.state))
-        change = self._change(self.state["params"], p0)
+        # the initial weights again, made anew from the seed rather than
+        # held as a copy beside the state
+        change = self._change(self.state["params"],
+                              self._make(seed_key(seed)))
         self.prog = {"losses": [float(v) for v in jax.device_get(losses)],
                      "grad": [float(v) for v in jax.device_get(grad)],
                      "change": [float(v) for v in jax.device_get(change)]}
-        del p0, weights
+        self.prog.update(self._first_cut(self.state["ws"]["a"][0],
+                                         self.first[0][0]))
+        del weights
+        phases.mark("first_steps")
         for _ in range(cell.traffic.get("warmup_steps", 2)):
             self.step()
+        phases.mark("warmup")
+        phases.say()
 
     # ---------------------------------------------------------------- feed
     def _put(self, batch):
@@ -111,6 +122,25 @@ class Run:
         sums = check.accum_sums(state["opt"]["a"][0]) + \
             check.accum_sums(state["opt"]["b"])
         return jax.jit(lambda s: [jnp.sqrt(x) for x in s])(sums)
+
+    @staticmethod
+    def _first_cut(ws, batch_idx) -> Dict:
+        """Round 1's exchanged Z and dZ as party A's workset ring keeps
+        them (zeros where no slot holds that batch), for a ring that
+        stores them as they are; a quantized ring gives nothing."""
+        import jax
+        import numpy as np
+        buf = ws["buf"]
+        if not all(isinstance(buf[k], jax.Array) and
+                   jax.numpy.issubdtype(buf[k].dtype, jax.numpy.floating)
+                   for k in ("z", "dz")):
+            return {}
+        held = np.flatnonzero(np.asarray(ws["batch_idx"]) == batch_idx)
+        out = {}
+        for k in ("z", "dz"):
+            ring = np.asarray(jax.device_get(buf[k]), np.float32)
+            out[k] = ring[held[0]] if held.size else np.zeros_like(ring[0])
+        return out
 
     @staticmethod
     def _change(params, p0):
@@ -137,20 +167,6 @@ class Run:
     def hlo_text(self) -> str:
         return self.compiled.as_text()
 
-    # ----------------------------------------------------------- rooflines
-    def kernels(self) -> Dict[str, Dict]:
-        """Bytes one call of each cache kernel must move in this job."""
-        import flops
-        job, B = self.cell.job, self.rows_per_step
-        width = self.cell.family.cut_width(self.cell.cfg, job)
-        if job["cache_dtype"] == "int8":
-            return {"fused_sample_q8": {"bytes_per_call": flops.kernel_bytes(
-                        "fused_sample_q8", rows=B, width=width)},
-                    "quantize_sr": {"bytes_per_call": flops.kernel_bytes(
-                        "quantize_sr", rows=B, width=width)}}
-        return {"fused_sample": {"bytes_per_call": flops.kernel_bytes(
-            "fused_sample", rows=B, width=width)}}
-
     # ---------------------------------------------------------- reference
     def release(self):
         """Free the program's state before the reference runs."""
@@ -164,6 +180,7 @@ class Run:
         precision step below the configuration's); ``rows`` > 0 keeps
         that many rows of each batch."""
         import jax
+        import numpy as np
         fa, lb = self.cell.family.reference(self.cell.cfg)
         w = jax.tree_util.tree_map(lambda x: x.astype(jax.numpy.float32),
                                    self._make(seed_key(self.seed)))
@@ -172,12 +189,11 @@ class Run:
         with jax.default_matmul_precision("highest"):
             out = celu_ref.run(fa, lb, w, self.first, self.cell.job,
                                policy, rows=rows)
-        sums = jax.tree_util.tree_leaves(out["accum1"]["a"]) + \
-            jax.tree_util.tree_leaves(out["accum1"]["b"])
-        grad = jax.jit(lambda s: [jax.numpy.sqrt(x.sum()) for x in s])(sums)
         change = jax.jit(check.change_norms)(out["params"], w)
         return {"losses": out["losses"],
-                "grad": [float(v) for v in jax.device_get(grad)],
+                "z": np.asarray(jax.device_get(out["z1"]), np.float32),
+                "dz": np.asarray(jax.device_get(out["dz1"]), np.float32),
+                "grad": [float(v) for v in jax.device_get(out["grad1"])],
                 "change": [float(v) for v in jax.device_get(change)]}
 
     def numbers(self, ref: Dict = None, prog: Dict = None) -> Dict:

@@ -20,9 +20,11 @@ def data_params(cfg, job):
             "vocab": cfg["vocab"], "batch": job["batch"]}
 
 
-def cut_width(cfg, job):
-    """Values per row of the exchanged cut activation Z_A."""
-    return cfg["z_dim"]
+def train_flops_per_row(cfg, job):
+    """FLOPs of one forward + backward pass per training row: 6 per
+    matmul weight (``flops.dlrm_wdl_weights``); gathers not counted."""
+    import flops
+    return 6.0 * flops.dlrm_wdl_weights(cfg)
 
 
 def program(cfg):

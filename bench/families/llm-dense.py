@@ -23,9 +23,11 @@ def data_params(cfg, job):
             "seq_len": job["seq_len"], "batch": job["batch"]}
 
 
-def cut_width(cfg, job):
-    """Values per row (sequence) of the exchanged cut activation Z_A."""
-    return job["seq_len"] * cfg["hidden_size"]
+def train_flops_per_row(cfg, job):
+    """FLOPs of one forward + backward pass per training sequence of
+    ``seq_len`` tokens (``flops.llm_flops_per_token``)."""
+    import flops
+    return job["seq_len"] * flops.llm_flops_per_token(cfg, job["seq_len"])
 
 
 def arch(cfg):

@@ -1,4 +1,4 @@
-"""Operations and bytes from shapes: the counts behind MFU and rooflines.
+"""Operations from shapes: the counts behind MFU.
 
 Every count here is what the algorithm needs, worked out from the
 configuration and the job's shapes, never read from the program:
@@ -9,8 +9,6 @@ configuration and the job's shapes, never read from the program:
   head_dim * seq per token).  Recomputation (activation checkpointing)
   and Algorithm 2's extra ad-hoc derivative pass at the label party are
   not counted, and neither are embedding gathers.
-* ``kernel_bytes``: the HBM bytes one call of a Pallas kernel must move:
-  the workset ring's side of it, read or written once.
 """
 from __future__ import annotations
 
@@ -29,13 +27,6 @@ def dlrm_wdl_weights(cfg: Dict) -> int:
             + _mlp_weights([2 * z, hidden[-1], 1]))
 
 
-def pod_wdl_weights(cfg: Dict) -> int:
-    """Matmul weights of the pod demo WDL: two towers (F*E -> hidden ->
-    z) and Party B's top MLP (2z -> z -> 1)."""
-    F, E, h, z = cfg["fields"], cfg["embed_dim"], cfg["hidden"], cfg["z_dim"]
-    return 2 * _mlp_weights([F * E, h, z]) + _mlp_weights([2 * z, z, 1])
-
-
 def llm_layer_weights(cfg: Dict) -> int:
     d, H, Kv = cfg["hidden_size"], cfg["num_attention_heads"], \
         cfg["num_key_value_heads"]
@@ -51,45 +42,20 @@ def llm_weights(cfg: Dict) -> int:
             + d * cfg["vocab_size"])
 
 
-def train_flops_per_row(family: str, cfg: Dict, job: Dict) -> float:
-    """FLOPs of one forward + backward pass per training row."""
-    if family == "dlrm-wdl":
-        return 6.0 * dlrm_wdl_weights(cfg)
-    if family == "pod-wdl":
-        return 6.0 * pod_wdl_weights(cfg)
-    if family == "llm-dense":
-        S = job["seq_len"]
-        attn = 12.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] \
-            * cfg["head_dim"] * S
-        return S * (6.0 * llm_weights(cfg) + attn)
-    raise ValueError(f"no FLOP count for family {family!r}")
+def llm_flops_per_token(cfg: Dict, seq_len: int) -> float:
+    """FLOPs of one forward + backward pass per token: 6 per matmul
+    weight, and attention's scores and values over ``seq_len`` (12 *
+    layers * heads * head_dim * seq_len)."""
+    attn = 12.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] \
+        * cfg["head_dim"] * seq_len
+    return 6.0 * llm_weights(cfg) + attn
 
 
-def round_flops(family: str, cfg: Dict, job: Dict) -> float:
+def round_flops(family, cfg: Dict, job: Dict) -> float:
     """Model FLOPs of one round: 1 + n_local forward + backward passes
-    over the batch (n_local = 0 for vanilla training)."""
+    over the batch (n_local = 0 for vanilla training).  ``family`` is
+    the configuration's family module, which gives the FLOPs of one
+    pass per training row (``train_flops_per_row``)."""
     n_local = job["R"] if job["protocol"] == "celu" else 0
     return (1 + n_local) * job["batch"] \
-        * train_flops_per_row(family, cfg, job)
-
-
-def kernel_bytes(kernel: str, *, rows: int, width: int,
-                 itemsize: int = 4) -> int:
-    """HBM bytes one call of ``kernel`` must move over a (rows, width)
-    cut-statistic entry stored ``itemsize`` bytes a value: only the
-    workset ring's side, which lives in HBM between rounds.  The ad-hoc
-    statistic, the uniforms and the cotangent are intermediates of the
-    round that a fused program can keep on chip (XLA places such
-    operands in VMEM where they fit), so they are not counted.
-
-    fused_sample / fused_sample_q8: read the sampled slot's Z and dZ rows
-    (plus one fp32 scale per row each when quantized).  quantize_sr:
-    write the slot's int8 codes and one fp32 scale per row."""
-    n = rows * width
-    if kernel == "fused_sample":
-        return 2 * n * itemsize
-    if kernel == "fused_sample_q8":
-        return 2 * (n + rows * 4)
-    if kernel == "quantize_sr":
-        return n + rows * 4
-    raise ValueError(f"no byte count for kernel {kernel!r}")
+        * family.train_flops_per_row(cfg, job)

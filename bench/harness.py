@@ -265,7 +265,6 @@ def main(argv=None, t_start: Optional[float] = None, fault: str = ""):
         trace.kernels, trace.names = T.kernel_ops(hlo), T.op_names(hlo)
         shutil.rmtree(tdir, ignore_errors=True)
     mem = peak_bytes(run.devices)
-    kernels = run.kernels()
     run.release()
 
     numbers = run.numbers()
@@ -277,9 +276,9 @@ def main(argv=None, t_start: Optional[float] = None, fault: str = ""):
 
     rec = {"cell": cell, "window": win, "setup_s": setup_s,
            "trace": trace, "traced_steps": traced_steps,
-           "round_flops": flops.round_flops(cell.cfg["family"], cell.cfg,
+           "round_flops": flops.round_flops(cell.family, cell.cfg,
                                             cell.job),
-           "mesh_devices": len(run.devices), "kernels": kernels,
+           "mesh_devices": len(run.devices),
            "peaks": peaks_for(dev["kind"], args.rehearse)}
     metrics = read_metrics(cell, rec, bool(args.trace))
     device = dict(dev, memory_peak_bytes=mem)

@@ -4,7 +4,8 @@ The reference computes in float32 with matmuls at ``HIGHEST`` precision
 and stores parameters as the configuration states.  The control, which
 proves the comparison can fail, is the same reference one precision step
 below what the configuration states (``bfloat16`` below float32,
-``float8`` e4m3 below bfloat16).  A policy rounds the inputs of every
+``float8`` e4m3 below bfloat16, with one scale per tensor as float8
+training keeps it).  A policy rounds the inputs of every
 matmul, the activations between layers, and the parameters as they are
 stored after each optimizer step; the optimizer's own arithmetic stays
 float32, as a mixed-precision trainer keeps it.
@@ -28,12 +29,57 @@ def round_e4m3(x):
     return jnp.clip(q, -448.0, 448.0)
 
 
+def round_e4m3_scaled(x):
+    """Round to float8 e4m3 with one scale per tensor: the tensor's
+    largest magnitude maps to e4m3's largest finite value (448), as
+    float8 training scales a tensor before it casts it, so that small
+    tensors such as gradients keep their values instead of flushing to
+    zero.  A derivative passes through the rounding unchanged, as
+    through a cast (``round`` itself has none)."""
+    x = x.astype(jnp.float32)
+    amax = jax.lax.stop_gradient(jnp.max(jnp.abs(x)))
+    s = jnp.where(amax > 0, amax / 448.0, 1.0)
+    return x + jax.lax.stop_gradient(round_e4m3(x / s) * s - x)
+
+
+@jax.custom_vjp
+def round_grad_e4m3_scaled(x):
+    """The identity, whose derivative rounds the cotangent to float8
+    e4m3 with one scale per tensor: put on a matmul's output, it makes
+    both matmuls of its backward pass take float8 operands, as float8
+    training computes gradients."""
+    return x
+
+
+def _rg_fwd(x):
+    return x, None
+
+
+def _rg_bwd(_, ct):
+    return (round_e4m3_scaled(ct).astype(ct.dtype),)
+
+
+round_grad_e4m3_scaled.defvjp(_rg_fwd, _rg_bwd)
+
+
+def round_bf16(x):
+    """Round float32 values to the nearest bfloat16 (ties to even), by
+    their bits.  A pair of converts, float32 to bfloat16 and back, would
+    do the same, but XLA may drop such a pair as excess precision (it
+    does on the TPU), and the reference would then keep float32 where
+    the configuration stores bfloat16."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    b = (b + jnp.uint32(0x7FFF) + ((b >> 16) & jnp.uint32(1))) \
+        & jnp.uint32(0xFFFF0000)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
 def _round(x, dtype: str):
     if dtype == "float32":
         return x
     if dtype == "bfloat16":
-        return x.astype(jnp.bfloat16).astype(jnp.float32)
-    return round_e4m3(x)
+        return round_bf16(x)
+    return round_e4m3_scaled(x)
 
 
 class Policy:
@@ -52,7 +98,7 @@ class Policy:
     def cast(self, x):
         """A value as this policy computes with it."""
         if self.compute == "float8":
-            return round_e4m3(x).astype(jnp.bfloat16)
+            return round_e4m3_scaled(x).astype(jnp.bfloat16)
         return x.astype(self.act)
 
     def store(self, p):
@@ -60,8 +106,11 @@ class Policy:
         return _round(p, self.storage)
 
     def dot(self, spec: str, a, b):
-        return jnp.einsum(spec, self.cast(a), self.cast(b),
-                          precision=HIGHEST, preferred_element_type=self.act)
+        out = jnp.einsum(spec, self.cast(a), self.cast(b),
+                         precision=HIGHEST, preferred_element_type=self.act)
+        if self.compute == "float8":
+            out = round_grad_e4m3_scaled(out)
+        return out
 
 
 PRECISIONS = ("float32", "bfloat16", "float8")

@@ -1,15 +1,12 @@
 """Every cell's timed program compiles for a described TPU v5e, at the
-cell's own shapes, without a chip: one v5e chip for the one-chip cells,
-a two-device ``pod`` mesh of a described 2x2 host for the pod round.
+cell's own shapes, without a chip: one chip of a described v5e host.
 What the chip's compiler refuses is found here for no chip time.
 Nothing runs; shapes come from ``jax.eval_shape``.
 
     JAX_PLATFORMS=cpu python3 -m pytest -q bench/tests/test_chip_compile.py
 """
-import math
 import os
 
-import numpy as np
 import pytest
 
 import harness
@@ -83,33 +80,3 @@ def test_round_compiles_for_v5e(topo, cell, kernels):
     text = _round_text(cell, topo)
     for k in kernels:
         assert f"{k}/pallas_call" in text, k
-
-
-def test_pod_round_compiles_for_two_v5e_chips(topo):
-    import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-    from repro.optim import make_optimizer
-
-    from test_pod import staged_cell
-    cell = staged_cell(rehearse=False)
-    cfg, job = cell.cfg, cell.job
-    mesh = Mesh(np.array(topo.devices[:2]), ("pod",))
-    put = NamedSharding(mesh, PartitionSpec("pod"))
-    pod_protocol, shapes = cell.family.program(cfg)
-    opt = make_optimizer("adagrad", job["lr"])
-    _, opt_state, ws = jax.eval_shape(
-        lambda k: pod_protocol.init_pod_state(
-            k, None, opt, n_fields=cfg["fields"], vocab=cfg["vocab"],
-            batch=job["batch"], W=job["W"], embed_dim=cfg["embed_dim"],
-            z_dim=cfg["z_dim"], hidden=cfg["hidden"]),
-        jax.random.PRNGKey(0))
-    B, F = job["batch"], cfg["fields"]
-    x = jax.ShapeDtypeStruct((2, B, F), np.int32, sharding=put)
-    y = jax.ShapeDtypeStruct((2, B), np.float32, sharding=put)
-    rnd = pod_protocol.make_pod_round(
-        mesh, opt, R=job["R"], cos_xi=math.cos(math.radians(job["xi"])),
-        pipeline_depth=job["pipeline_depth"])
-    text = rnd.lower(_shaped(shapes, put), _shaped(opt_state, put),
-                     _shaped(ws, put), x, y).compile().as_text()
-    assert "collective-permute" in text

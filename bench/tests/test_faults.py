@@ -8,9 +8,6 @@ it is (correct), once for every fault the cell can have (not correct):
 * ``half_batch``: half of every batch left out, the mean taken over the
   rest.
 
-(The pod driver's third fault, the exchange between chips left out, is
-in ``test_pod.py``.)
-
 And the control: the reference, computed one precision step below what
 the configuration states, put in the program's place, is not correct.
 

@@ -1,7 +1,16 @@
-"""flops.py against hand counts at the cells' shapes."""
+"""flops.py and the families' FLOP counts against hand counts at the
+cells' shapes."""
 import flops
+import harness
 
-WDL = {"fields_a": 26, "fields_b": 13, "vocab": 1024, "embed_dim": 16,
+WDL_FAMILY = harness.load_module(
+    harness.os.path.join(harness.BENCH, "families", "dlrm-wdl.py"),
+    "family_dlrm_wdl")
+LLM_FAMILY = harness.load_module(
+    harness.os.path.join(harness.BENCH, "families", "llm-dense.py"),
+    "family_llm_dense")
+
+WDL = {"fields_a": 26, "fields_b": 13, "vocab": 649281, "embed_dim": 16,
        "z_dim": 256, "hidden": [512, 256]}
 SMOLLM = {"num_hidden_layers": 32, "hidden_size": 960,
           "intermediate_size": 2560, "num_attention_heads": 15,
@@ -16,9 +25,9 @@ def test_wdl_weights_and_round():
     top = 512 * 256 + 256
     assert flops.dlrm_wdl_weights(WDL) == a + b + top == 844032
     job = {"protocol": "celu", "R": 5, "batch": 4096}
-    assert flops.round_flops("dlrm-wdl", WDL, job) == 6 * 4096 * 6 * 844032
+    assert flops.round_flops(WDL_FAMILY, WDL, job) == 6 * 4096 * 6 * 844032
     job["protocol"] = "vanilla"
-    assert flops.round_flops("dlrm-wdl", WDL, job) == 4096 * 6 * 844032
+    assert flops.round_flops(WDL_FAMILY, WDL, job) == 4096 * 6 * 844032
 
 
 def test_smollm_weights():
@@ -28,18 +37,5 @@ def test_smollm_weights():
     assert flops.llm_weights(SMOLLM) == 32 * layer + d * d + d * 49152
     job = {"protocol": "celu", "R": 5, "batch": 8, "seq_len": 512}
     per_token = 6 * flops.llm_weights(SMOLLM) + 12 * 32 * 15 * 64 * 512
-    assert flops.round_flops("llm-dense", SMOLLM, job) == \
+    assert flops.round_flops(LLM_FAMILY, SMOLLM, job) == \
         6 * 8 * 512 * per_token
-
-
-def test_kernel_bytes():
-    # fp32 ring slot over (4096, 256): Z and dZ rows
-    assert flops.kernel_bytes("fused_sample", rows=4096, width=256) == \
-        2 * 4096 * 256 * 4
-    # int8 ring slot over (8, 491520): Z and dZ codes plus row scales
-    n = 8 * 491520
-    assert flops.kernel_bytes("fused_sample_q8", rows=8, width=491520) == \
-        2 * (n + 32)
-    # the quantizer writes the slot's codes and row scales
-    assert flops.kernel_bytes("quantize_sr", rows=8, width=491520) == \
-        n + 32
