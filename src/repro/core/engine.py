@@ -99,6 +99,22 @@ from .workset import (CastLeaf, Quant4Leaf, QuantLeaf, decode_entry,
                       workset_insert,
                       workset_sample)  # noqa: F401  (workset_sample re-exported: historical import site)
 
+# Stable ``jax.named_scope`` names of the round's stages, and of the parts
+# inside them.  Every operation of a round sits under exactly one stage;
+# a profile reader (``bench/stages.py``) charges device time to these.
+# Scopes only write location metadata: the compiled device code is the
+# same with or without them.  (A Pallas kernel's serialized body keeps its
+# locations, so a round holding one gets a new persistent-cache key.)
+EXCHANGE_COMPUTE = "exchange_compute"
+EXCHANGE_APPLY = "exchange_apply"
+LOCAL_SCAN = "local_scan"
+STAGE_SCOPES = (EXCHANGE_COMPUTE, EXCHANGE_APPLY, LOCAL_SCAN)
+OPTIMIZER = "optimizer"             # opt.update + apply_updates
+WORKSET_INSERT = "workset_insert"   # the fresh exchange into the ring
+WORKSET_DRAW = "workset_draw"       # a local update's slot from the ring
+LOCAL_GRAD = "local_grad"           # a local update's weighted gradient
+LEAF_SCOPES = (OPTIMIZER, WORKSET_INSERT, WORKSET_DRAW, LOCAL_GRAD)
+
 
 class KPartyTask(NamedTuple):
     """K-party split-model interface (information-flow discipline at
@@ -735,6 +751,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
             1.0 + jnp.float32(lr_damping)
             * jnp.asarray(staleness).astype(jnp.float32))
 
+    @jax.named_scope(EXCHANGE_COMPUTE)
     def exchange_compute(params, tstate, batches_a, batch_b, comm_rounds):
         pas, pb = params["a"], params["b"]
         K = len(pas)
@@ -780,6 +797,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         return {"zs": zs, "dzs": dzs, "g_as": g_as, "g_b": g_b,
                 "loss": loss, "tstate": new_tstate}
 
+    @jax.named_scope(EXCHANGE_APPLY)
     def exchange_apply(state, fresh, batches_a, batch_b, batch_idx,
                        staleness=None):
         pas, pb = state["params"]["a"], state["params"]["b"]
@@ -787,31 +805,39 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         zs, dzs = fresh["zs"], fresh["dzs"]
         damp = _damp(staleness)
         new_pas, new_oas = [], []
-        for i in range(K):
-            upd, oa = opt.update(fresh["g_as"][i], state["opt"]["a"][i],
-                                 pas[i])
+        with jax.named_scope(OPTIMIZER):
+            for i in range(K):
+                upd, oa = opt.update(fresh["g_as"][i],
+                                     state["opt"]["a"][i], pas[i])
+                if damp is not None:
+                    upd = jax.tree_util.tree_map(lambda u: u * damp, upd)
+                new_pas.append(apply_updates(pas[i], upd))
+                new_oas.append(oa)
+            upd_b, ob = opt.update(fresh["g_b"], state["opt"]["b"], pb)
             if damp is not None:
-                upd = jax.tree_util.tree_map(lambda u: u * damp, upd)
-            new_pas.append(apply_updates(pas[i], upd))
-            new_oas.append(oa)
-        upd_b, ob = opt.update(fresh["g_b"], state["opt"]["b"], pb)
-        if damp is not None:
-            upd_b = jax.tree_util.tree_map(lambda u: u * damp, upd_b)
+                upd_b = jax.tree_util.tree_map(lambda u: u * damp, upd_b)
 
-        # rounding noise for quantized-at-rest caches (unused — and DCE'd —
-        # by the fp32 table); per-party keys keep the SR noise independent
-        ins_rng = jax.random.fold_in(rng_keys["insert"],
-                                     state["comm_rounds"])
-        ws_a = [workset_insert(state["ws"]["a"][i],
-                               {"z": zs[i], "dz": dzs[i],
-                                "batch": batches_a[i]}, batch_idx,
-                               rng=jax.random.fold_in(ins_rng, i))
-                for i in range(K)]
-        ws_b = workset_insert(state["ws"]["b"],
-                              {"z": zs, "dz": dzs, "batch": batch_b},
-                              batch_idx, rng=jax.random.fold_in(ins_rng, K))
+        with jax.named_scope(WORKSET_INSERT):
+            # rounding noise for quantized-at-rest caches (unused — and
+            # DCE'd — by the fp32 table); per-party keys keep the SR noise
+            # independent
+            ins_rng = jax.random.fold_in(rng_keys["insert"],
+                                         state["comm_rounds"])
+            ws_a = [workset_insert(state["ws"]["a"][i],
+                                   {"z": zs[i], "dz": dzs[i],
+                                    "batch": batches_a[i]}, batch_idx,
+                                   rng=jax.random.fold_in(ins_rng, i))
+                    for i in range(K)]
+            ws_b = workset_insert(state["ws"]["b"],
+                                  {"z": zs, "dz": dzs, "batch": batch_b},
+                                  batch_idx,
+                                  rng=jax.random.fold_in(ins_rng, K))
+        # traced after the inserts, as it always was: the program's
+        # operation order stays as it is
+        with jax.named_scope(OPTIMIZER):
+            new_pb = apply_updates(pb, upd_b)
         new_state = {
-            "params": {"a": new_pas, "b": apply_updates(pb, upd_b)},
+            "params": {"a": new_pas, "b": new_pb},
             "opt": {"a": new_oas, "b": ob},
             "ws": {"a": ws_a, "b": ws_b},
             "steps": {"a": [s + 1 for s in state["steps"]["a"]],
@@ -821,6 +847,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         }
         return new_state, {"loss": fresh["loss"]}
 
+    @jax.named_scope(LOCAL_SCAN)
     def local_scan(state, staleness=None, party_mask=None):
         # ``party_mask`` ((K+1,) float32 — a_0..a_{K-1}, b; None = all
         # live) freezes a dropped-out party's local updates: its draw's
@@ -861,46 +888,52 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
             pas, oas, wsas, nas = list(pas), list(oas), list(wsas), list(nas)
             w_means, w_zeros = [], []
             for i in range(K):
-                ki = None if draw_key is None \
-                    else jax.random.fold_in(draw_key, i)
-                wsas[i], slot, _, valid = workset_draw(
-                    wsas[i], celu.R, celu.sampling, rng=ki,
-                    pipeline_staleness=s_loc)
-                vf = valid.astype(jnp.float32)
-                if party_mask is not None:
-                    vf = vf * party_mask[i]
-                g, w = local_grad_a_cached(
-                    task.forward_a, pas[i], wsas[i], slot, cos_xi,
-                    weighting=celu.weighting, fused=fused,
-                    cache_fused=celu.cache_fused, mask=vf,
-                    pipeline_staleness=s_loc)
-                upd, oas[i] = opt.update(g, oas[i], pas[i])
-                uf = vf if damp is None else vf * damp
-                upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
-                pas[i] = apply_updates(pas[i], upd)
+                with jax.named_scope(WORKSET_DRAW):
+                    ki = None if draw_key is None \
+                        else jax.random.fold_in(draw_key, i)
+                    wsas[i], slot, _, valid = workset_draw(
+                        wsas[i], celu.R, celu.sampling, rng=ki,
+                        pipeline_staleness=s_loc)
+                    vf = valid.astype(jnp.float32)
+                    if party_mask is not None:
+                        vf = vf * party_mask[i]
+                with jax.named_scope(LOCAL_GRAD):
+                    g, w = local_grad_a_cached(
+                        task.forward_a, pas[i], wsas[i], slot, cos_xi,
+                        weighting=celu.weighting, fused=fused,
+                        cache_fused=celu.cache_fused, mask=vf,
+                        pipeline_staleness=s_loc)
+                with jax.named_scope(OPTIMIZER):
+                    upd, oas[i] = opt.update(g, oas[i], pas[i])
+                    uf = vf if damp is None else vf * damp
+                    upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
+                    pas[i] = apply_updates(pas[i], upd)
                 nas[i] = nas[i] + (valid.astype(jnp.int32)
                                    if party_mask is None
                                    else (vf > 0).astype(jnp.int32))
                 w_means.append(jnp.mean(w))
                 w_zeros.append(jnp.mean(w == 0.0))
 
-            kb = None if draw_key is None \
-                else jax.random.fold_in(draw_key, K)
-            wsb, slot_b, _, valid = workset_draw(
-                wsb, celu.R, celu.sampling, rng=kb,
-                pipeline_staleness=s_loc)
-            vf = valid.astype(jnp.float32)
-            if party_mask is not None:
-                vf = vf * party_mask[K]
-            g, w = local_grad_b_cached(
-                task.loss_b, pb, wsb, slot_b, cos_xi,
-                weighting=celu.weighting, fused=fused,
-                cache_fused=celu.cache_fused, mask=vf,
-                pipeline_staleness=s_loc)
-            upd, ob = opt.update(g, ob, pb)
-            uf = vf if damp is None else vf * damp
-            upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
-            pb = apply_updates(pb, upd)
+            with jax.named_scope(WORKSET_DRAW):
+                kb = None if draw_key is None \
+                    else jax.random.fold_in(draw_key, K)
+                wsb, slot_b, _, valid = workset_draw(
+                    wsb, celu.R, celu.sampling, rng=kb,
+                    pipeline_staleness=s_loc)
+                vf = valid.astype(jnp.float32)
+                if party_mask is not None:
+                    vf = vf * party_mask[K]
+            with jax.named_scope(LOCAL_GRAD):
+                g, w = local_grad_b_cached(
+                    task.loss_b, pb, wsb, slot_b, cos_xi,
+                    weighting=celu.weighting, fused=fused,
+                    cache_fused=celu.cache_fused, mask=vf,
+                    pipeline_staleness=s_loc)
+            with jax.named_scope(OPTIMIZER):
+                upd, ob = opt.update(g, ob, pb)
+                uf = vf if damp is None else vf * damp
+                upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
+                pb = apply_updates(pb, upd)
             nb = nb + (valid.astype(jnp.int32) if party_mask is None
                        else (vf > 0).astype(jnp.int32))
             w_means.append(jnp.mean(w))
@@ -1371,58 +1404,68 @@ def make_pod_round(mesh, opt: Optimizer, *, R: int, cos_xi: float,
         """Runs per-pod (inside shard_map, pod axis size 2).
 
         Shapes here are the PER-POD view: params leaves (1, ...), x (1,B,F).
+        The stages carry the engine's scope names, so a pod trace reads
+        like a one-chip one.
         """
-        pod = jax.lax.axis_index(tp.axis)
-        tower = jax.tree_util.tree_map(lambda a: a[0], params["tower"])
-        top = jax.tree_util.tree_map(lambda a: a[0], params["top"])
-        xb = x[0]                                   # (B, F)
-        yb = y[0]                                   # (B,)
+        with jax.named_scope(EXCHANGE_COMPUTE):
+            pod = jax.lax.axis_index(tp.axis)
+            tower = jax.tree_util.tree_map(lambda a: a[0], params["tower"])
+            top = jax.tree_util.tree_map(lambda a: a[0], params["top"])
+            xb = x[0]                                   # (B, F)
+            yb = y[0]                                   # (B,)
 
         # ---- R local updates, round-robin over the given workset ---------
+        @jax.named_scope(LOCAL_SCAN)
         def local_scan(params, opt_state, ws):
             W = ws["z"].shape[1]
 
             def local_step(carry, j):
                 params, opt_state, cursor = carry
-                t = ws["time"][0]
-                n_alive = jnp.minimum(t, W)
-                slot_j = jnp.mod(cursor, jnp.maximum(n_alive, 1))
-                # decode the at-rest ring precision (bf16 cache upcasts;
-                # the fp32 ring is untouched — bit-identical)
-                zs = ws["z"][0, slot_j].astype(jnp.float32)
-                dzs = ws["dz"][0, slot_j].astype(jnp.float32)
-                xs = ws["x"][0, slot_j]
-                ys_ = ws["y"][0, slot_j]
-                tower_j = jax.tree_util.tree_map(lambda a: a[0],
-                                                 params["tower"])
-                top_j = jax.tree_util.tree_map(lambda a: a[0],
-                                               params["top"])
+                with jax.named_scope(WORKSET_DRAW):
+                    t = ws["time"][0]
+                    n_alive = jnp.minimum(t, W)
+                    slot_j = jnp.mod(cursor, jnp.maximum(n_alive, 1))
+                    # decode the at-rest ring precision (bf16 cache
+                    # upcasts; the fp32 ring is untouched — bit-identical)
+                    zs = ws["z"][0, slot_j].astype(jnp.float32)
+                    dzs = ws["dz"][0, slot_j].astype(jnp.float32)
+                    xs = ws["x"][0, slot_j]
+                    ys_ = ws["y"][0, slot_j]
+                with jax.named_scope(LOCAL_GRAD):
+                    tower_j = jax.tree_util.tree_map(lambda a: a[0],
+                                                     params["tower"])
+                    top_j = jax.tree_util.tree_map(lambda a: a[0],
+                                                   params["top"])
 
-                # Party A: ad-hoc forward, cosine vs stale Z, weighted
-                # stale ∇Z
-                g_tower_a, _ = local_grad_a(
-                    tower_fwd, tower_j, {"z": zs, "dz": dzs, "batch": xs},
-                    cos_xi, weighting=weighting, fused=fused,
-                    pipeline_staleness=pipeline_depth)
+                    # Party A: ad-hoc forward, cosine vs stale Z, weighted
+                    # stale ∇Z
+                    g_tower_a, _ = local_grad_a(
+                        tower_fwd, tower_j,
+                        {"z": zs, "dz": dzs, "batch": xs},
+                        cos_xi, weighting=weighting, fused=fused,
+                        pipeline_staleness=pipeline_depth)
 
-                # Party B: stale Z_A + ad-hoc own tower; weight by ∇Z_A
-                # cosine
-                g_b, _ = local_grad_b(
-                    b_loss, {"top": top_j, "tower": tower_j},
-                    {"z": [zs], "dz": [dzs], "batch": {"x": xs, "y": ys_}},
-                    cos_xi, weighting=weighting, fused=fused,
-                    pipeline_staleness=pipeline_depth)
-                g_top_b, g_tower_b = g_b["top"], g_b["tower"]
+                    # Party B: stale Z_A + ad-hoc own tower; weight by ∇Z_A
+                    # cosine
+                    g_b, _ = local_grad_b(
+                        b_loss, {"top": top_j, "tower": tower_j},
+                        {"z": [zs], "dz": [dzs],
+                         "batch": {"x": xs, "y": ys_}},
+                        cos_xi, weighting=weighting, fused=fused,
+                        pipeline_staleness=pipeline_depth)
+                    g_top_b, g_tower_b = g_b["top"], g_b["tower"]
 
-                is_a_ = (pod == 0)
-                g_tower_sel = jax.tree_util.tree_map(
-                    lambda ga, gb: jnp.where(is_a_, ga, gb)[None],
-                    g_tower_a, g_tower_b)
-                g_top_sel = jax.tree_util.tree_map(
-                    lambda g: jnp.where(is_a_, 0.0, g)[None], g_top_b)
-                grads_j = {"tower": g_tower_sel, "top": g_top_sel}
-                upd_j, opt_state = opt.update(grads_j, opt_state, params)
-                params = apply_updates(params, upd_j)
+                    is_a_ = (pod == 0)
+                    g_tower_sel = jax.tree_util.tree_map(
+                        lambda ga, gb: jnp.where(is_a_, ga, gb)[None],
+                        g_tower_a, g_tower_b)
+                    g_top_sel = jax.tree_util.tree_map(
+                        lambda g: jnp.where(is_a_, 0.0, g)[None], g_top_b)
+                    grads_j = {"tower": g_tower_sel, "top": g_top_sel}
+                with jax.named_scope(OPTIMIZER):
+                    upd_j, opt_state = opt.update(grads_j, opt_state,
+                                                  params)
+                    params = apply_updates(params, upd_j)
                 return (params, opt_state, cursor + 1), None
 
             (params, opt_state, _), _ = jax.lax.scan(
@@ -1431,9 +1474,11 @@ def make_pod_round(mesh, opt: Optimizer, *, R: int, cos_xi: float,
             return params, opt_state
 
         # ---- fresh exchange (the paper's communication worker) ----------
-        z_mine, tower_vjp = jax.vjp(lambda tpm: tower_fwd(tpm, xb), tower)
-        # Z_A: pod0 -> pod1 (pod0 receives pod1's Z_B slot, unused)
-        z_a_at_b = tp.send_up(z_mine)                # on pod 1: Z_A
+        with jax.named_scope(EXCHANGE_COMPUTE):
+            z_mine, tower_vjp = jax.vjp(lambda tpm: tower_fwd(tpm, xb),
+                                        tower)
+            # Z_A: pod0 -> pod1 (pod0 receives pod1's Z_B slot, unused)
+            z_a_at_b = tp.send_up(z_mine)                # on pod 1: Z_A
 
         if pipeline_depth:
             # Overlap window: the scan reads only the dispatch-time params
@@ -1445,52 +1490,60 @@ def make_pod_round(mesh, opt: Optimizer, *, R: int, cos_xi: float,
             # the post-scan params when the stats "arrive".
             params, opt_state = local_scan(params, opt_state, ws)
 
-        def loss_fn(top_p, z_a):
-            return jnp.mean(top_loss(top_p, z_a, z_mine, yb))
-        (loss, (g_top, dz_a)) = (loss_fn(top, z_a_at_b),
-                                 jax.grad(loss_fn, argnums=(0, 1))(
-                                     top, z_a_at_b))
-        # ∇Z_A: pod1 -> pod0 (the symmetric permute)
-        dz_back = tp.send_down(dz_a)
+        with jax.named_scope(EXCHANGE_COMPUTE):
+            def loss_fn(top_p, z_a):
+                return jnp.mean(top_loss(top_p, z_a, z_mine, yb))
+            (loss, (g_top, dz_a)) = (loss_fn(top, z_a_at_b),
+                                     jax.grad(loss_fn, argnums=(0, 1))(
+                                         top, z_a_at_b))
+            # ∇Z_A: pod1 -> pod0 (the symmetric permute)
+            dz_back = tp.send_down(dz_a)
 
-        is_a = (pod == 0)
-        # Party A's tower cotangent is the received ∇Z_A; Party B's is its
-        # local ∂loss/∂Z_B.  Both computed, selected by pod id.
-        dz_b_local = jax.grad(
-            lambda z_b: jnp.mean(top_loss(top, z_a_at_b, z_b, yb)))(z_mine)
-        cot = jnp.where(is_a, dz_back, dz_b_local)
-        (g_tower,) = tower_vjp(cot)
-        g_top = jax.tree_util.tree_map(
-            lambda g: jnp.where(is_a, 0.0, g), g_top)
+            is_a = (pod == 0)
+            # Party A's tower cotangent is the received ∇Z_A; Party B's is
+            # its local ∂loss/∂Z_B.  Both computed, selected by pod id.
+            dz_b_local = jax.grad(
+                lambda z_b: jnp.mean(top_loss(top, z_a_at_b, z_b, yb)))(
+                    z_mine)
+            cot = jnp.where(is_a, dz_back, dz_b_local)
+            (g_tower,) = tower_vjp(cot)
+            g_top = jax.tree_util.tree_map(
+                lambda g: jnp.where(is_a, 0.0, g), g_top)
 
         # ---- update + insert into the device-resident workset -----------
-        grads = {"tower": jax.tree_util.tree_map(lambda g: g[None], g_tower),
-                 "top": jax.tree_util.tree_map(lambda g: g[None], g_top)}
-        upd, opt_state = opt.update(grads, opt_state, params)
-        params = apply_updates(params, upd)
+        with jax.named_scope(EXCHANGE_APPLY), jax.named_scope(OPTIMIZER):
+            grads = {
+                "tower": jax.tree_util.tree_map(lambda g: g[None], g_tower),
+                "top": jax.tree_util.tree_map(lambda g: g[None], g_top)}
+            upd, opt_state = opt.update(grads, opt_state, params)
+            params = apply_updates(params, upd)
 
-        W = ws["z"].shape[1]
-        slot = jnp.mod(ws["time"][0], W)
-        ws = dict(ws)
-        # cache: stale z (own Z for A's weighting / Z_A for B), stale dz,
-        # own features (+ labels at B)
-        z_cache = jnp.where(is_a, z_mine, z_a_at_b)
-        dz_cache = jnp.where(is_a, dz_back, dz_a)
-        ws["z"] = jax.lax.dynamic_update_index_in_dim(
-            ws["z"], z_cache[None].astype(ws["z"].dtype), slot, 1)
-        ws["dz"] = jax.lax.dynamic_update_index_in_dim(
-            ws["dz"], dz_cache[None].astype(ws["dz"].dtype), slot, 1)
-        ws["x"] = jax.lax.dynamic_update_index_in_dim(
-            ws["x"], xb[None], slot, 1)
-        ws["y"] = jax.lax.dynamic_update_index_in_dim(
-            ws["y"], yb[None], slot, 1)
-        ws["time"] = ws["time"] + 1
+        with jax.named_scope(EXCHANGE_APPLY), \
+                jax.named_scope(WORKSET_INSERT):
+            W = ws["z"].shape[1]
+            slot = jnp.mod(ws["time"][0], W)
+            ws = dict(ws)
+            # cache: stale z (own Z for A's weighting / Z_A for B), stale
+            # dz, own features (+ labels at B)
+            z_cache = jnp.where(is_a, z_mine, z_a_at_b)
+            dz_cache = jnp.where(is_a, dz_back, dz_a)
+            ws["z"] = jax.lax.dynamic_update_index_in_dim(
+                ws["z"], z_cache[None].astype(ws["z"].dtype), slot, 1)
+            ws["dz"] = jax.lax.dynamic_update_index_in_dim(
+                ws["dz"], dz_cache[None].astype(ws["dz"].dtype), slot, 1)
+            ws["x"] = jax.lax.dynamic_update_index_in_dim(
+                ws["x"], xb[None], slot, 1)
+            ws["y"] = jax.lax.dynamic_update_index_in_dim(
+                ws["y"], yb[None], slot, 1)
+            ws["time"] = ws["time"] + 1
 
         if not pipeline_depth:
             # sequential schedule: the scan runs after the insert, over the
             # just-refreshed workset and post-exchange params
             params, opt_state = local_scan(params, opt_state, ws)
-        return params, opt_state, ws, loss[None]
+        with jax.named_scope(EXCHANGE_COMPUTE):
+            loss = loss[None]
+        return params, opt_state, ws, loss
 
     pp = P(tp.axis)  # every party-stacked leaf shards dim0 over pod
     fn = jax.shard_map(
