@@ -36,6 +36,8 @@ AUDIT_B = 64        # audited batch geometry (fusable: 64 | BLOCK_B)
 AUDIT_Z = 8         # cut-layer width
 AUDIT_FA = 6        # feature-party input width
 AUDIT_FB = 5        # label-party own-feature width
+AUDIT_T = 3         # fields of each party's embedding table (tables=True)
+AUDIT_V = 11        # rows of each field's table
 
 
 @dataclass(frozen=True)
@@ -53,6 +55,10 @@ class AuditCase:
     # (core/faults.py) — the audit proves the absorbed residuals still
     # clear the boundary theorem on the retransmission
     dropped: bool = False
+    # each party also embeds field ids through a declared table, so the
+    # rounds take the row path (core/rows.py): compact tables, id remap,
+    # row-sparse optimizer steps
+    tables: bool = False
 
 
 def default_cases(quick: bool = False) -> List[AuditCase]:
@@ -70,12 +76,14 @@ def default_cases(quick: bool = False) -> List[AuditCase]:
              ] + ([kw["wire_dtype"]] if kw.get("wire_dtype",
                                                "float32") != "float32"
                   else [])
-               + (["drop"] if kw.get("dropped") else [])))
+               + (["drop"] if kw.get("dropped") else [])
+               + (["tables"] if kw.get("tables") else [])))
         return AuditCase(**kw)
 
     if quick:
-        return [mk(), mk(compression="topk_int8", dp_sigma=0.3, depth=2,
-                         cache_dtype="int8"),
+        return [mk(), mk(tables=True, depth=1),
+                mk(compression="topk_int8", dp_sigma=0.3, depth=2,
+                   cache_dtype="int8"),
                 mk(compression="topk_int8", dp_sigma=0.3, depth=2,
                    cache_dtype="int8", dropped=True),
                 mk(depth=2, compression="int8", cache_dtype="int4"),
@@ -105,6 +113,11 @@ def default_cases(quick: bool = False) -> List[AuditCase]:
         cases.append(mk(K=K, depth=2, compression="topk_int8",
                         cache_dtype="int8", dp_sigma=0.3, dropped=True))
     cases.append(mk(depth=2, compression="topk_int8", dropped=True))
+    # the row path: embedding tables at every depth shape, K > 1
+    for depth in (0, 1, 2):
+        cases.append(mk(depth=depth, tables=True))
+    cases.append(mk(K=3, depth=2, compression="topk_int8",
+                    cache_dtype="int8", dp_sigma=0.3, tables=True))
     # dedupe (the sweeps overlap at the origin), keep first occurrence
     seen, out = set(), []
     for c in cases:
@@ -117,16 +130,23 @@ def default_cases(quick: bool = False) -> List[AuditCase]:
 # --------------------------------------------------------------------------
 # Toy-but-faithful K-party task
 # --------------------------------------------------------------------------
-def _toy_task(K: int):
+def _toy_task(K: int, tables: bool = False):
     import jax.numpy as jnp
 
     from ..core import engine as E
+    from ..core.rows import RowTables, Tables
+
+    def embed(p, batch):
+        if not tables:
+            return 0.0
+        f = jnp.arange(AUDIT_T)[None, :]
+        return p["embed"][f, batch["ids"]].sum(axis=1)
 
     def forward_a(p, batch):
-        return jnp.tanh(batch["x"] @ p["w"] + p["b"])
+        return jnp.tanh(batch["x"] @ p["w"] + p["b"] + embed(p, batch))
 
     def loss_b(p, z_list, batch):
-        own = jnp.tanh(batch["x"] @ p["w_own"])
+        own = jnp.tanh(batch["x"] @ p["w_own"] + embed(p, batch))
         h = jnp.concatenate(list(z_list) + [own], axis=1)
         logits = (h @ p["w_top"])[:, 0]
         y = batch["y"]
@@ -134,7 +154,6 @@ def _toy_task(K: int):
             jnp.log1p(jnp.exp(-jnp.abs(logits)))
         return li, jnp.float32(0.0)
 
-    task = E.KPartyTask(forward_a, loss_b)
     params = {
         "a": [{"w": jnp.zeros((AUDIT_FA, AUDIT_Z)),
                "b": jnp.zeros((AUDIT_Z,))} for _ in range(K)],
@@ -144,6 +163,16 @@ def _toy_task(K: int):
     batches_a = [{"x": jnp.zeros((AUDIT_B, AUDIT_FA))} for _ in range(K)]
     batch_b = {"x": jnp.zeros((AUDIT_B, AUDIT_FB)),
                "y": jnp.zeros((AUDIT_B,))}
+    if not tables:
+        return E.KPartyTask(forward_a, loss_b), params, batches_a, batch_b
+    table = jnp.zeros((AUDIT_T, AUDIT_V, AUDIT_Z))
+    ids = jnp.zeros((AUDIT_B, AUDIT_T), jnp.int32)
+    params = {"a": [{**p, "embed": table} for p in params["a"]],
+              "b": {**params["b"], "embed": table}}
+    batches_a = [{**b, "ids": ids} for b in batches_a]
+    batch_b = {**batch_b, "ids": ids}
+    decl = Tables("ids", ("embed",))
+    task = E.KPartyTask(forward_a, loss_b, RowTables(a=decl, b=decl))
     return task, params, batches_a, batch_b
 
 
@@ -231,10 +260,11 @@ def _out_state_tags(st_sds, K: int):
     }
 
 
-# w_mean / w_zero_frac aggregate per-party weight statistics across ALL
-# parties by design (sim-level diagnostics) — host rule skipped (None).
+# w_mean / w_zero_frac / rows_updated aggregate per-party statistics
+# across ALL parties by design (sim-level diagnostics) — host rule skipped
+# (None).
 _METRIC_ALLOWED = {"loss": frozenset({"b"}), "local_steps": _PUBLIC,
-                   "w_mean": None, "w_zero_frac": None}
+                   "w_mean": None, "w_zero_frac": None, "rows_updated": None}
 
 
 def _out_metric_tags(m_sds):
@@ -374,7 +404,7 @@ def trace_case(case: AuditCase, transport=None) -> CaseResult:
     from .wire_audit import audit_wire
 
     celu = _make_celu(case)
-    task, params, batches_a, batch_b = _toy_task(case.K)
+    task, params, batches_a, batch_b = _toy_task(case.K, case.tables)
     opt = make_optimizer("adagrad", 0.1)
     tp_inner = transport if transport is not None \
         else E.make_transport(celu)

@@ -92,6 +92,7 @@ import jax.numpy as jnp
 
 from ..configs.base import CELUConfig, validate_pipeline_depth
 from ..optim import Optimizer, apply_updates
+from .rows import RowTables, compact
 from .weighting import (instance_weights, pipeline_attenuation,
                         static_staleness, xi_to_cos)
 from .workset import (CastLeaf, Quant4Leaf, QuantLeaf, decode_entry,
@@ -122,10 +123,15 @@ class KPartyTask(NamedTuple):
 
         forward_a(params_a_i, batch_a_i) -> Z_i
         loss_b(params_b, [Z_1..Z_K], batch_b) -> (per-instance loss, aux)
-    """
+
+    ``row_tables`` (optional) declares each party's field-indexed
+    embedding tables (``core.rows``): with an optimizer that has a row
+    update, the rounds differentiate and step only the rows each batch
+    touches."""
     forward_a: Callable[[Any, Any], jnp.ndarray]
     loss_b: Callable[[Any, Sequence[jnp.ndarray], Any],
                      Tuple[jnp.ndarray, jnp.ndarray]]
+    row_tables: Optional[RowTables] = None
 
 
 def lift_two_party(task) -> KPartyTask:
@@ -133,7 +139,8 @@ def lift_two_party(task) -> KPartyTask:
     interface (``loss_b`` over ``[Z_1..Z_K]``, K=1)."""
     return KPartyTask(
         task.forward_a,
-        lambda pb, z_list, batch_b: task.loss_b(pb, z_list[0], batch_b))
+        lambda pb, z_list, batch_b: task.loss_b(pb, z_list[0], batch_b),
+        task.row_tables)
 
 
 def lift_two_party_params(params: Dict[str, Any]) -> Dict[str, Any]:
@@ -509,7 +516,7 @@ def _fused_ring_sample(slot, z_new, z_store, dz_store, cos_xi: float):
 def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
                         weighting: bool = True, fused: bool = True,
                         cache_fused: bool = True, mask=None,
-                        pipeline_staleness=0):
+                        pipeline_staleness=0, tables=None):
     """Feature-party local update straight off the workset ring — the
     single-pass hot path.  Only the party's OWN cached features are
     gathered (the forward needs them); the cut statistics ⟨Z, ∇Z⟩ are
@@ -518,9 +525,13 @@ def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
     full-precision entry copy in HBM.  ``cache_fused=False`` (or an
     unfusable batch tiling, or ``weighting``/``fused`` off) falls back to
     materialize-then-weight — the bit-exact reference composition.
-    Returns (grads, weights)."""
+    Returns (grads, weights); with ``tables`` (the party's declared
+    ``core.rows.Tables``) the gradient is taken on the compact tables of
+    the slot's ids, and (grads, weights, (rows, n)) carries
+    ``core.rows.compact``'s rows tree and count."""
     buf = ws["buf"]
     batch = jax.tree_util.tree_map(lambda b: b[slot], buf["batch"])
+    params_a, batch, rows, n = compact(params_a, batch, tables)
     z_new, vjp = jax.vjp(lambda p: forward_a(p, batch), params_a)
     if weighting and fused and cache_fused and _fusable(z_new):
         w, cot = _fused_ring_sample(slot, z_new, buf["z"], buf["dz"],
@@ -530,11 +541,12 @@ def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
             w = w * mask
             cot = cot * mask
         (g,) = vjp(cot.astype(z_new.dtype))
-        return g, w
-    entry = workset_entry(ws, slot)
-    return _grad_a_tail(z_new, vjp, entry["z"], entry["dz"], cos_xi,
-                        weighting=weighting, fused=fused, mask=mask,
-                        pipeline_staleness=pipeline_staleness)
+    else:
+        entry = workset_entry(ws, slot)
+        g, w = _grad_a_tail(z_new, vjp, entry["z"], entry["dz"], cos_xi,
+                            weighting=weighting, fused=fused, mask=mask,
+                            pipeline_staleness=pipeline_staleness)
+    return (g, w) if tables is None else (g, w, (rows, n))
 
 
 def local_grad_b(loss_b, params_b, entry, cos_xi: float, *,
@@ -596,7 +608,7 @@ def _fused_ring_weights(slot, dz_new, dz_store, cos_xi: float):
 def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
                         weighting: bool = True, fused: bool = True,
                         cache_fused: bool = True, mask=None,
-                        pipeline_staleness=0):
+                        pipeline_staleness=0, tables=None):
     """Label-party local update straight off the workset ring.  The loss
     CONSUMES the decoded Z list, so the K ``z`` entries must still be
     materialized — but the K ``dz`` entries' only consumer is the
@@ -605,9 +617,11 @@ def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
     the decoded ∇Z list in HBM.  ``cache_fused=False`` (or an unfusable
     batch tiling, or ``weighting``/``fused`` off) falls back to
     materialize-then-weight — the bit-exact reference composition.
-    Returns (grads, weights)."""
+    Returns (grads, weights), or with ``tables`` (grads on the compact
+    tables, weights, (rows, n)) as :func:`local_grad_a_cached` does."""
     buf = ws["buf"]
     batch_b = jax.tree_util.tree_map(lambda b: b[slot], buf["batch"])
+    params_b, batch_b, rows, n = compact(params_b, batch_b, tables)
     zs = decode_entry(jax.tree_util.tree_map(lambda b: b[slot], buf["z"]))
     K = len(zs)
     if weighting:
@@ -637,7 +651,7 @@ def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
         return jnp.mean(w * li) + aux
 
     g = jax.grad(weighted)(params_b)
-    return g, w
+    return (g, w) if tables is None else (g, w, (rows, n))
 
 
 # --------------------------------------------------------------------------
@@ -732,7 +746,16 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
     keys replacing the engine's fixed bases — a job with the default keys
     reproduces the scalar engine's rng chain exactly, a job with
     seed-folded keys draws an independent stream.  Both may be tracers
-    (closed over during a jit/vmap trace of the caller)."""
+    (closed over during a jit/vmap trace of the caller).
+
+    Row path: where the task declares its embedding tables
+    (``task.row_tables``) and ``opt`` has a row update
+    (``opt.update_rows``), each party's gradient is taken on compact
+    tables of its batch's distinct ids (``core.rows.compact``) and its
+    optimizer steps write those rows only; the exchange payload carries
+    the ids (``fresh["rows"]``), and the metrics count the (field, id)
+    entries the steps wrote (``rows_updated``).  Otherwise every leaf
+    takes the dense step."""
     if cos_xi is None:
         cos_xi = xi_to_cos(celu.xi_degrees)
     if rng_keys is None:
@@ -741,6 +764,22 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                     "draw": jax.random.PRNGKey(29)}
     s_pipe = int(pipeline_staleness)
     uniform = celu.sampling == "uniform"
+    rt = task.row_tables
+    if rt is None or opt.update_rows is None:
+        rt = RowTables()
+    tab_a, tab_b = rt
+    row_path = tab_a is not None or tab_b is not None
+
+    def _step(g, ostate, p, rows, scale):
+        """One party's optimizer step -> (params, opt state): the row
+        update where ``rows`` names tables, else the dense one (its
+        update times ``scale`` when given)."""
+        if rows is not None:
+            return opt.update_rows(g, ostate, p, rows, scale)
+        upd, ostate = opt.update(g, ostate, p)
+        if scale is not None:
+            upd = jax.tree_util.tree_map(lambda u: u * scale, upd)
+        return apply_updates(p, upd), ostate
 
     def _damp(staleness):
         """1 / (1 + c*s) update scale; None when the static path (or a
@@ -768,17 +807,20 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         down_res = list(tstate["down"]) if "down" in tstate else [None] * K
 
         # uplinks: every A_i's forward -> Z_i, released in wire precision
-        zs, vjps = [], []
+        zs, vjps, rows_a = [], [], []
         for i in range(K):
-            z, vjp = jax.vjp(
-                lambda p, i=i: task.forward_a(p, batches_a[i]), pas[i])
+            pa, ba, rows, n = compact(pas[i], batches_a[i], tab_a)
+            z, vjp = jax.vjp(lambda p, ba=ba: task.forward_a(p, ba), pa)
             z, up_res[i] = tp.send(keys[2 * i], z, up_res[i], "up")
             zs.append(z)
             vjps.append(vjp)
+            rows_a.append((rows, n))
 
         # Party B: loss + grads wrt (params_b, all Z_i); ∇Z_i are downlinks
+        pb, bb, rows_b, n_b = compact(pb, batch_b, tab_b)
+
         def mean_loss(p, z_list):
-            li, aux = task.loss_b(p, z_list, batch_b)
+            li, aux = task.loss_b(p, z_list, bb)
             return jnp.mean(li) + aux
         loss, (g_b, dzs) = jax.value_and_grad(
             mean_loss, argnums=(0, 1))(pb, zs)
@@ -794,8 +836,12 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
 
         # every A_i's backward with its (wire-precision) cotangent
         g_as = [vjps[i](dzs[i].astype(zs[i].dtype))[0] for i in range(K)]
-        return {"zs": zs, "dzs": dzs, "g_as": g_as, "g_b": g_b,
-                "loss": loss, "tstate": new_tstate}
+        fresh = {"zs": zs, "dzs": dzs, "g_as": g_as, "g_b": g_b,
+                 "loss": loss, "tstate": new_tstate}
+        if row_path:
+            fresh["rows"] = {"a": [r for r, _ in rows_a], "b": rows_b,
+                             "n": _count([n for _, n in rows_a] + [n_b])}
+        return fresh
 
     @jax.named_scope(EXCHANGE_APPLY)
     def exchange_apply(state, fresh, batches_a, batch_b, batch_idx,
@@ -803,19 +849,23 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         pas, pb = state["params"]["a"], state["params"]["b"]
         K = len(pas)
         zs, dzs = fresh["zs"], fresh["dzs"]
+        rows = fresh.get("rows", {"a": [None] * K, "b": None})
         damp = _damp(staleness)
         new_pas, new_oas = [], []
         with jax.named_scope(OPTIMIZER):
             for i in range(K):
-                upd, oa = opt.update(fresh["g_as"][i],
-                                     state["opt"]["a"][i], pas[i])
-                if damp is not None:
-                    upd = jax.tree_util.tree_map(lambda u: u * damp, upd)
-                new_pas.append(apply_updates(pas[i], upd))
+                pa, oa = _step(fresh["g_as"][i], state["opt"]["a"][i],
+                               pas[i], rows["a"][i], damp)
+                new_pas.append(pa)
                 new_oas.append(oa)
-            upd_b, ob = opt.update(fresh["g_b"], state["opt"]["b"], pb)
-            if damp is not None:
-                upd_b = jax.tree_util.tree_map(lambda u: u * damp, upd_b)
+            if rows["b"] is not None:
+                new_pb, ob = _step(fresh["g_b"], state["opt"]["b"], pb,
+                                   rows["b"], damp)
+            else:
+                upd_b, ob = opt.update(fresh["g_b"], state["opt"]["b"], pb)
+                if damp is not None:
+                    upd_b = jax.tree_util.tree_map(lambda u: u * damp,
+                                                   upd_b)
 
         with jax.named_scope(WORKSET_INSERT):
             # rounding noise for quantized-at-rest caches (unused — and
@@ -832,10 +882,11 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                                   {"z": zs, "dz": dzs, "batch": batch_b},
                                   batch_idx,
                                   rng=jax.random.fold_in(ins_rng, K))
-        # traced after the inserts, as it always was: the program's
-        # operation order stays as it is
-        with jax.named_scope(OPTIMIZER):
-            new_pb = apply_updates(pb, upd_b)
+        if rows["b"] is None:
+            # traced after the inserts, as it always was: the program's
+            # operation order stays as it is
+            with jax.named_scope(OPTIMIZER):
+                new_pb = apply_updates(pb, upd_b)
         new_state = {
             "params": {"a": new_pas, "b": new_pb},
             "opt": {"a": new_oas, "b": ob},
@@ -845,7 +896,10 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
             "comm_rounds": state["comm_rounds"] + 1,
             "transport": fresh["tstate"],
         }
-        return new_state, {"loss": fresh["loss"]}
+        m = {"loss": fresh["loss"]}
+        if "rows" in fresh:
+            m["rows_updated"] = fresh["rows"]["n"]
+        return new_state, m
 
     @jax.named_scope(LOCAL_SCAN)
     def local_scan(state, staleness=None, party_mask=None):
@@ -886,7 +940,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                 pas, oas, wsas, nas, pb, ob, wsb, nb = carry
                 draw_key = None
             pas, oas, wsas, nas = list(pas), list(oas), list(wsas), list(nas)
-            w_means, w_zeros = [], []
+            w_means, w_zeros, counts = [], [], []
             for i in range(K):
                 with jax.named_scope(WORKSET_DRAW):
                     ki = None if draw_key is None \
@@ -898,16 +952,16 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                     if party_mask is not None:
                         vf = vf * party_mask[i]
                 with jax.named_scope(LOCAL_GRAD):
-                    g, w = local_grad_a_cached(
+                    g, w, *rows = local_grad_a_cached(
                         task.forward_a, pas[i], wsas[i], slot, cos_xi,
                         weighting=celu.weighting, fused=fused,
                         cache_fused=celu.cache_fused, mask=vf,
-                        pipeline_staleness=s_loc)
+                        pipeline_staleness=s_loc, tables=tab_a)
+                    rows, n = rows[0] if rows else (None, None)
                 with jax.named_scope(OPTIMIZER):
-                    upd, oas[i] = opt.update(g, oas[i], pas[i])
                     uf = vf if damp is None else vf * damp
-                    upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
-                    pas[i] = apply_updates(pas[i], upd)
+                    pas[i], oas[i] = _step(g, oas[i], pas[i], rows, uf)
+                counts.append(n)
                 nas[i] = nas[i] + (valid.astype(jnp.int32)
                                    if party_mask is None
                                    else (vf > 0).astype(jnp.int32))
@@ -924,16 +978,16 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                 if party_mask is not None:
                     vf = vf * party_mask[K]
             with jax.named_scope(LOCAL_GRAD):
-                g, w = local_grad_b_cached(
+                g, w, *rows = local_grad_b_cached(
                     task.loss_b, pb, wsb, slot_b, cos_xi,
                     weighting=celu.weighting, fused=fused,
                     cache_fused=celu.cache_fused, mask=vf,
-                    pipeline_staleness=s_loc)
+                    pipeline_staleness=s_loc, tables=tab_b)
+                rows, n = rows[0] if rows else (None, None)
             with jax.named_scope(OPTIMIZER):
-                upd, ob = opt.update(g, ob, pb)
                 uf = vf if damp is None else vf * damp
-                upd = jax.tree_util.tree_map(lambda u: u * uf, upd)
-                pb = apply_updates(pb, upd)
+                pb, ob = _step(g, ob, pb, rows, uf)
+            counts.append(n)
             nb = nb + (valid.astype(jnp.int32) if party_mask is None
                        else (vf > 0).astype(jnp.int32))
             w_means.append(jnp.mean(w))
@@ -941,6 +995,8 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
 
             lm = {"w_mean": sum(w_means) * scale,
                   "w_zero_frac": sum(w_zeros) * scale}
+            if row_path:
+                lm["rows_updated"] = _count(counts)
             carry = (pas, oas, wsas, nas, pb, ob, wsb, nb)
             if uniform:
                 carry = carry + (j + 1,)
@@ -963,11 +1019,29 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
             "comm_rounds": state["comm_rounds"],
             "transport": state["transport"],
         }
-        return state, {"local_steps": sum(nas) + nb,
-                       "w_mean": jnp.mean(lm["w_mean"]),
-                       "w_zero_frac": jnp.mean(lm["w_zero_frac"])}
+        out = {"local_steps": sum(nas) + nb,
+               "w_mean": jnp.mean(lm["w_mean"]),
+               "w_zero_frac": jnp.mean(lm["w_zero_frac"])}
+        if row_path:
+            out["rows_updated"] = jnp.sum(lm["rows_updated"])
+        return state, out
 
     return exchange_compute, exchange_apply, local_scan
+
+
+def _count(ns):
+    """Sum of the parties' row counts (None: a party without tables)."""
+    return sum(n for n in ns if n is not None)
+
+
+def merge_metrics(m, lm):
+    """A round's metrics from its exchange's (``m``) and its local
+    scan's (``lm``); on the row path their ``rows_updated`` add up."""
+    out = {**m, **lm}
+    if "rows_updated" in m and "rows_updated" in lm:
+        with jax.named_scope(LOCAL_SCAN), jax.named_scope(OPTIMIZER):
+            out["rows_updated"] = m["rows_updated"] + lm["rows_updated"]
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -1002,8 +1076,7 @@ def make_round(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         state, m = exchange_apply(state, fresh, batches_a, batch_b,
                                   batch_idx)
         state, lm = local_scan(state)
-        m.update(lm)
-        return state, m
+        return state, merge_metrics(m, lm)
 
     if jit:
         return jax.jit(round_fn, donate_argnums=(0,) if donate else ())
@@ -1065,10 +1138,26 @@ class RoundState(NamedTuple):
                 "transport": self.transport}
 
 
-def _zero_local_metrics():
+def _zero_local_metrics(rows: bool = False):
     zero = jnp.float32(0.0)
-    return {"local_steps": jnp.int32(0), "w_mean": zero,
-            "w_zero_frac": zero}
+    out = {"local_steps": jnp.int32(0), "w_mean": zero, "w_zero_frac": zero}
+    if rows:
+        out["rows_updated"] = jnp.int32(0)
+    return out
+
+
+def _flush_metrics(scans, merged=()):
+    """A drain's metrics: its local scans' steps summed and weight
+    statistics averaged; on the row path ``rows_updated`` sums the scans'
+    and the ``merged`` exchanges' rows."""
+    n = len(scans)
+    out = {"local_steps": sum(s["local_steps"] for s in scans),
+           "w_mean": sum(s["w_mean"] for s in scans) / n,
+           "w_zero_frac": sum(s["w_zero_frac"] for s in scans) / n}
+    if "rows_updated" in scans[0]:
+        out["rows_updated"] = sum(s["rows_updated"]
+                                  for s in list(scans) + list(merged))
+    return out
 
 
 class PipelinedEngine:
@@ -1274,8 +1363,7 @@ class PipelinedEngine:
                 rs, m = self.merge(rs)
             else:
                 m = {"loss": jnp.float32(jnp.nan)}   # warmup: queue filling
-        m.update(lm)
-        return rs, m
+        return rs, merge_metrics(m, lm)
 
     def flush(self, rs: RoundState) -> Tuple[RoundState, Dict[str, Any]]:
         """Drain the pipeline.  Depth 0 is a no-op; depth 1 runs the one
@@ -1286,19 +1374,15 @@ class PipelinedEngine:
             return rs, _zero_local_metrics()
         if self.depth == 1:
             return self.local(rs)
-        scans = []
+        scans, merged = [], []
         while rs.pending:
             rs, lm = self.local(rs)
             scans.append(lm)
-            rs, _ = self.merge(rs)
+            rs, m = self.merge(rs)
+            merged.append(m)
         rs, lm = self.local(rs)
         scans.append(lm)
-        n = len(scans)
-        return rs, {
-            "local_steps": sum(l["local_steps"] for l in scans),
-            "w_mean": sum(l["w_mean"] for l in scans) / n,
-            "w_zero_frac": sum(l["w_zero_frac"] for l in scans) / n,
-        }
+        return rs, _flush_metrics(scans, merged)
 
     def finalize(self, rs: RoundState) -> Dict[str, Any]:
         """Back to the engine's canonical state dict."""

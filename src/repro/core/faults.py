@@ -55,7 +55,7 @@ import numpy as np
 from ..configs.base import CELUConfig, FaultPlan
 from ..optim import Optimizer
 from .engine import KPartyTask, PendingExchange, PipelinedEngine, \
-    RoundState, _zero_local_metrics
+    RoundState, _flush_metrics, _zero_local_metrics, merge_metrics
 
 
 @dataclasses.dataclass(frozen=True)
@@ -288,8 +288,7 @@ class ChaosEngine(PipelinedEngine):
         self.now = t + 1
         if m is None:
             m = {"loss": jnp.float32(jnp.nan)}
-        m.update(lm)
-        return rs, m
+        return rs, merge_metrics(m, lm)
 
     def flush(self, rs: RoundState) -> Tuple[RoundState, Dict[str, Any]]:
         """Drain the queue.  Outstanding merges complete regardless of
@@ -304,7 +303,7 @@ class ChaosEngine(PipelinedEngine):
             # got its in-step scan (depth-0 order is merge THEN scan)
             return rs, _zero_local_metrics()
         K = len(rs.params["a"])
-        scans = []
+        scans, merged = [], []
         while rs.pending:
             t = self.now
             rs, lm = self._chaos_local(
@@ -314,7 +313,8 @@ class ChaosEngine(PipelinedEngine):
                 else t
             if self._arrival:
                 self._arrival.pop(0)
-            rs, _ = self.merge(rs, staleness=t - dr)
+            rs, m = self.merge(rs, staleness=t - dr)
+            merged.append(m)
             self._last_merged_dispatch = max(
                 self._last_merged_dispatch, dr)
             self.counters["merges"] += 1
@@ -322,14 +322,7 @@ class ChaosEngine(PipelinedEngine):
         t = self.now
         rs, lm = self._chaos_local(rs, t, self.schedule.party_mask(t, K))
         scans.append(lm)
-        if not scans:
-            return rs, _zero_local_metrics()
-        n = len(scans)
-        return rs, {
-            "local_steps": sum(s["local_steps"] for s in scans),
-            "w_mean": sum(s["w_mean"] for s in scans) / n,
-            "w_zero_frac": sum(s["w_zero_frac"] for s in scans) / n,
-        }
+        return rs, _flush_metrics(scans, merged)
 
 
 def make_chaos_engine(task: KPartyTask, opt: Optimizer, celu: CELUConfig,

@@ -35,7 +35,7 @@ bandwidth=300 Mbps).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,13 +43,17 @@ import jax.numpy as jnp
 from ..configs.base import CELUConfig
 from ..optim import Optimizer
 from . import engine
+from .rows import RowTables
 
 
 class VFLTask(NamedTuple):
-    """Two-party split model interface (see module docstring)."""
+    """Two-party split model interface (see module docstring);
+    ``row_tables`` declares the parties' embedding tables
+    (``core.rows``)."""
     forward_a: Callable[[Any, Dict[str, Any]], jnp.ndarray]
     loss_b: Callable[[Any, jnp.ndarray, Dict[str, Any]],
                      Tuple[jnp.ndarray, jnp.ndarray]]
+    row_tables: Optional[RowTables] = None
 
 
 # --------------------------------------------------------------------------

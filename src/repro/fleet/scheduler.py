@@ -40,7 +40,8 @@ import numpy as np
 
 from ..configs.base import CELUConfig, validate_pipeline_depth
 from ..core.engine import (KPartyTask, PendingExchange, _make_stages,
-                           _zero_local_metrics, make_transport)
+                           _zero_local_metrics, make_transport,
+                           merge_metrics)
 from ..core.weighting import xi_to_cos
 from ..optim import make_optimizer
 
@@ -122,7 +123,8 @@ def average_flush_metrics(m: Dict[str, Any]) -> Dict[str, Any]:
     if "w_mean_scans" not in m:
         return dict(m)
     n = np.float32(np.asarray(m["n_scans"]))
-    out = {"local_steps": np.asarray(m["local_steps"])}
+    out = {k: np.asarray(m[k]) for k in ("local_steps", "rows_updated")
+           if k in m}
     for key in ("w_mean", "w_zero_frac"):
         acc = np.float32(0.0)
         for v in np.asarray(m[key + "_scans"], np.float32):
@@ -203,8 +205,7 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
                             batches_a, batch_b, state["comm_rounds"])
             state, m = apply_(state, fresh, batches_a, batch_b, batch_idx)
             state, lm = scan(state)
-            m.update(lm)
-            return fs._replace(state=state), m
+            return fs._replace(state=state), merge_metrics(m, lm)
         if depth == 1:
             # dispatch -> local (overlapped) -> merge; the queue fills and
             # drains within the step, so no cross-step slots are carried
@@ -212,8 +213,7 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
                             batches_a, batch_b, state["comm_rounds"])
             state, lm = scan(state)
             state, m = apply_(state, fresh, batches_a, batch_b, batch_idx)
-            m.update(lm)
-            return fs._replace(state=state), m
+            return fs._replace(state=state), merge_metrics(m, lm)
 
         # depth >= 2: device-side queue.  Dispatch chains the transport
         # residuals off the NEWEST in-flight exchange (dispatch-order
@@ -242,17 +242,18 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
             s = state["comm_rounds"] - oldest.dispatched_at
             state, m = apply_(state, oldest.fresh, oldest.batches_a,
                               oldest.batch_b, oldest.batch_idx, s)
-            return state, _pop(pending), n - 1, m["loss"]
+            return state, _pop(pending), n - 1, m
 
         def _warmup(args):
             state, pending, n = args
-            return state, pending, n, jnp.float32(jnp.nan)
+            m = {"loss": jnp.float32(jnp.nan)}
+            if "rows" in fresh:         # the row path: no step ran
+                m["rows_updated"] = jnp.int32(0)
+            return state, pending, n, m
 
-        state, pending, n, loss = jax.lax.cond(
+        state, pending, n, m = jax.lax.cond(
             n == depth, _merge, _warmup, (state, pending, n))
-        m = {"loss": loss}
-        m.update(lm)
-        return FleetRoundState(state, pending, n), m
+        return FleetRoundState(state, pending, n), merge_metrics(m, lm)
 
     def flush(fs: FleetRoundState, hyper: JobHyper):
         _, apply_, scan = _stages(hyper)
@@ -272,16 +273,17 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
         # which breaks bit-parity with PipelinedEngine.flush's eager
         # per-op adds.
         n0 = fs.n_pending
-        zeros = _zero_local_metrics()
+        zeros = _zero_local_metrics(rows="rows" in fs.pending.fresh)
 
         def _drain(args):
             state, pending, n = args
             state, lm = scan(state, n)
             oldest = _at(pending, jnp.int32(0))
             s = state["comm_rounds"] - oldest.dispatched_at
-            state, _ = apply_(state, oldest.fresh, oldest.batches_a,
+            state, m = apply_(state, oldest.fresh, oldest.batches_a,
                               oldest.batch_b, oldest.batch_idx, s)
-            return state, _pop(pending), n - 1, lm
+            m.pop("loss")
+            return state, _pop(pending), n - 1, merge_metrics(m, lm)
 
         def _idle(args):
             state, pending, n = args
@@ -302,6 +304,8 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
                                             for r in rows]),
             "n_scans": n0 + 1,
         }
+        if "rows_updated" in lm:
+            metrics["rows_updated"] = sum(r["rows_updated"] for r in rows)
         return FleetRoundState(state, pending, n), metrics
 
     return init, step, flush

@@ -11,6 +11,9 @@ Two DLRMs over vertically-partitioned categorical fields:
 
 Both expose the :class:`repro.core.protocol.VFLTask` interface with a
 logistic per-instance loss, plus ``predict_logits`` for AUC evaluation.
+Each declares its embedding tables (``core.rows``): a party's field ids
+index its tower's ``embed`` (and B's ``wide``) and nothing else, so the
+engine updates only the rows a batch touches.
 """
 from __future__ import annotations
 
@@ -21,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.protocol import VFLTask
+from ..core.rows import RowTables, Tables
 from .initializers import dense_init, zeros_init
 
 
@@ -98,7 +102,9 @@ def _wdl_task(cfg: DLRMConfig) -> VFLTask:
             jnp.exp(-jnp.abs(logit)))
         return li, jnp.float32(0.0)
 
-    return VFLTask(forward_a, loss_b)
+    return VFLTask(forward_a, loss_b, RowTables(
+        a=Tables("x_a", ("tower/embed",)),
+        b=Tables("x_b", ("tower/embed", "wide"))))
 
 
 def wdl_predict(params, cfg: DLRMConfig, batch_a, batch_b):
@@ -148,7 +154,8 @@ def _dssm_task(cfg: DLRMConfig) -> VFLTask:
             jnp.exp(-jnp.abs(logit)))
         return li, jnp.float32(0.0)
 
-    return VFLTask(forward_a, loss_b)
+    return VFLTask(forward_a, loss_b, RowTables(
+        a=Tables("x_a", ("tower/embed",)), b=Tables("x_b", ("tower/embed",))))
 
 
 def dssm_predict(params, cfg: DLRMConfig, batch_a, batch_b):
