@@ -18,6 +18,7 @@ ARCH_IDS = (
     "yi-34b",
     "xlstm-125m",
     "codeqwen1.5-7b",
+    "lfm2-8b-a1b",
 )
 
 DLRM_IDS = ("wdl-criteo", "dssm-avazu")

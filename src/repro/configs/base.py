@@ -18,14 +18,27 @@ def _round_up(x: int, m: int) -> int:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int           # the router's outputs: every expert routed over
     top_k: int
     n_shared: int = 0        # shared (always-on) experts, llama4-style
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01
+    router_aux_coef: float = 0.01   # Switch-style load-balance loss; 0: none
     # "ep" shards the expert dim over the model axis (all-to-all dispatch),
     # "tp" shards each expert's FFN over the model axis (no all-to-all).
     sharding: str = "tp"
+    d_expert: int = 0        # an expert's FFN width; 0 -> ArchConfig.d_ff
+    # "softmax": gates are the softmax of the top-k logits; "sigmoid":
+    # s = sigmoid(logits), top-k of s + expert bias (selection only),
+    # gates s[top-k] normalised over the top-k
+    router: str = "softmax"
+    # The experts this chip holds: ``n_held`` of them (0 -> all) from
+    # ``held_offset`` on.  Every token is routed over all ``n_experts``;
+    # the layer computes the held experts' part of the output.
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -73,6 +86,14 @@ class ArchConfig:
     cross_attn_every: int = 0      # vlm: every k-th layer cross-attends
     enc_layers: int = 0            # audio: encoder depth (Party A tower)
     qkv_bias: bool = False         # qwen-style attention bias
+    qk_norm: bool = False          # per-head RMSNorm on q and k before RoPE
+
+    # Per-layer kinds (lfm2-style hybrids), in published order: each layer's
+    # mixer ("conv" = gated short convolution, "full_attention") from
+    # ``layer_types``, and its FFN: dense for the first ``n_dense_layers``,
+    # the MoE after them.  Empty: every layer is the family's block.
+    layer_types: Tuple[str, ...] = ()
+    n_dense_layers: int = 0
 
     # attention window; 0 = full causal.  long_500k configs override this.
     sliding_window: int = 0
@@ -126,7 +147,9 @@ class ArchConfig:
         moe = None
         if self.moe is not None:
             moe = dataclasses.replace(
-                self.moe, n_experts=4, top_k=min(self.moe.top_k, 2))
+                self.moe, n_experts=4, top_k=min(self.moe.top_k, 2),
+                d_expert=64 if self.moe.d_expert else 0, n_held=0,
+                held_offset=0)
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",

@@ -127,11 +127,22 @@ class KPartyTask(NamedTuple):
     ``row_tables`` (optional) declares each party's field-indexed
     embedding tables (``core.rows``): with an optimizer that has a row
     update, the rounds differentiate and step only the rows each batch
-    touches."""
+    touches.
+
+    ``counted``: ``forward_a`` returns ``(Z_i, counts)`` and ``loss_b``
+    ``(per-instance loss, aux, counts)``, ``counts`` a dict of int32
+    scalars (``expert_rows``: the (token, slot) assignments a model's held
+    experts computed).  The round's metrics sum each over every forward
+    pass the round makes (``COUNTERS``)."""
     forward_a: Callable[[Any, Any], jnp.ndarray]
     loss_b: Callable[[Any, Sequence[jnp.ndarray], Any],
                      Tuple[jnp.ndarray, jnp.ndarray]]
     row_tables: Optional[RowTables] = None
+    counted: bool = False
+
+
+# Metrics that a round sums over its steps and passes, not averages.
+COUNTERS = ("rows_updated", "expert_rows")
 
 
 def lift_two_party(task) -> KPartyTask:
@@ -140,7 +151,13 @@ def lift_two_party(task) -> KPartyTask:
     return KPartyTask(
         task.forward_a,
         lambda pb, z_list, batch_b: task.loss_b(pb, z_list[0], batch_b),
-        task.row_tables)
+        task.row_tables, task.counted)
+
+
+def _add_counts(*cs):
+    """Sum of counters dicts (``None``: a pass that counts nothing)."""
+    cs = [c for c in cs if c]
+    return {k: sum(c[k] for c in cs) for k in cs[0]} if cs else {}
 
 
 def lift_two_party_params(params: Dict[str, Any]) -> Dict[str, Any]:
@@ -516,7 +533,7 @@ def _fused_ring_sample(slot, z_new, z_store, dz_store, cos_xi: float):
 def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
                         weighting: bool = True, fused: bool = True,
                         cache_fused: bool = True, mask=None,
-                        pipeline_staleness=0, tables=None):
+                        pipeline_staleness=0, tables=None, counted=False):
     """Feature-party local update straight off the workset ring — the
     single-pass hot path.  Only the party's OWN cached features are
     gathered (the forward needs them); the cut statistics ⟨Z, ∇Z⟩ are
@@ -528,11 +545,14 @@ def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
     Returns (grads, weights); with ``tables`` (the party's declared
     ``core.rows.Tables``) the gradient is taken on the compact tables of
     the slot's ids, and (grads, weights, (rows, n)) carries
-    ``core.rows.compact``'s rows tree and count."""
+    ``core.rows.compact``'s rows tree and count.  ``counted``: the
+    forward returns (Z, counts), and the counts end the result."""
     buf = ws["buf"]
     batch = jax.tree_util.tree_map(lambda b: b[slot], buf["batch"])
     params_a, batch, rows, n = compact(params_a, batch, tables)
-    z_new, vjp = jax.vjp(lambda p: forward_a(p, batch), params_a)
+    fwd = forward_a if counted else (lambda p, b: (forward_a(p, b), None))
+    z_new, vjp, counts = jax.vjp(lambda p: fwd(p, batch), params_a,
+                                 has_aux=True)
     if weighting and fused and cache_fused and _fusable(z_new):
         w, cot = _fused_ring_sample(slot, z_new, buf["z"], buf["dz"],
                                     cos_xi)
@@ -546,7 +566,8 @@ def local_grad_a_cached(forward_a, params_a, ws, slot, cos_xi: float, *,
         g, w = _grad_a_tail(z_new, vjp, entry["z"], entry["dz"], cos_xi,
                             weighting=weighting, fused=fused, mask=mask,
                             pipeline_staleness=pipeline_staleness)
-    return (g, w) if tables is None else (g, w, (rows, n))
+    out = (g, w) if tables is None else (g, w, (rows, n))
+    return out + (counts,) if counted else out
 
 
 def local_grad_b(loss_b, params_b, entry, cos_xi: float, *,
@@ -608,7 +629,7 @@ def _fused_ring_weights(slot, dz_new, dz_store, cos_xi: float):
 def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
                         weighting: bool = True, fused: bool = True,
                         cache_fused: bool = True, mask=None,
-                        pipeline_staleness=0, tables=None):
+                        pipeline_staleness=0, tables=None, counted=False):
     """Label-party local update straight off the workset ring.  The loss
     CONSUMES the decoded Z list, so the K ``z`` entries must still be
     materialized — but the K ``dz`` entries' only consumer is the
@@ -618,15 +639,25 @@ def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
     batch tiling, or ``weighting``/``fused`` off) falls back to
     materialize-then-weight — the bit-exact reference composition.
     Returns (grads, weights), or with ``tables`` (grads on the compact
-    tables, weights, (rows, n)) as :func:`local_grad_a_cached` does."""
+    tables, weights, (rows, n)) as :func:`local_grad_a_cached` does;
+    ``counted``: the loss returns counts as its third value, and the
+    counts of its passes end the result."""
     buf = ws["buf"]
     batch_b = jax.tree_util.tree_map(lambda b: b[slot], buf["batch"])
     params_b, batch_b, rows, n = compact(params_b, batch_b, tables)
     zs = decode_entry(jax.tree_util.tree_map(lambda b: b[slot], buf["z"]))
     K = len(zs)
+
+    def parts(p, zl):
+        out = loss_b(p, zl, batch_b)
+        return out if counted else out + (None,)
+
+    c_dz = None
     if weighting:
-        dz_new = jax.grad(
-            lambda zl: jnp.mean(loss_b(params_b, zl, batch_b)[0]))(
+        def adhoc(zl):
+            li, _, c = parts(params_b, zl)
+            return jnp.mean(li), c
+        dz_new, c_dz = jax.grad(adhoc, has_aux=True)(
             [z.astype(jnp.float32) for z in zs])
         if fused and cache_fused and _fusable(dz_new[0]):
             w = _fused_ring_weights(slot, dz_new[0], buf["dz"][0], cos_xi)
@@ -647,11 +678,12 @@ def local_grad_b_cached(loss_b, params_b, ws, slot, cos_xi: float, *,
         w = w * mask
 
     def weighted(p):
-        li, aux = loss_b(p, zs, batch_b)
-        return jnp.mean(w * li) + aux
+        li, aux, c = parts(p, zs)
+        return jnp.mean(w * li) + aux, c
 
-    g = jax.grad(weighted)(params_b)
-    return (g, w) if tables is None else (g, w, (rows, n))
+    g, c = jax.grad(weighted, has_aux=True)(params_b)
+    out = (g, w) if tables is None else (g, w, (rows, n))
+    return out + (_add_counts(c_dz, c),) if counted else out
 
 
 # --------------------------------------------------------------------------
@@ -671,6 +703,8 @@ def init_state(task: KPartyTask, params: Dict[str, Any], opt: Optimizer,
     K = len(params["a"])
     zs = [jax.eval_shape(task.forward_a, params["a"][i], batches_a[i])
           for i in range(K)]
+    if task.counted:
+        zs = [z for z, _ in zs]
     z_like = [jnp.zeros(z.shape, z.dtype) for z in zs]
     ws_a = [workset_init(celu.W, {"z": z_like[i], "dz": z_like[i],
                                   "batch": batches_a[i]},
@@ -769,6 +803,13 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         rt = RowTables()
     tab_a, tab_b = rt
     row_path = tab_a is not None or tab_b is not None
+    counted = task.counted
+    # forward_a -> (Z, counts); loss_b -> (loss, aux, counts); counts None
+    # for a task that counts nothing
+    fwd_a = task.forward_a if counted else \
+        (lambda p, b: (task.forward_a(p, b), None))
+    loss_b = task.loss_b if counted else \
+        (lambda p, zl, b: task.loss_b(p, zl, b) + (None,))
 
     def _step(g, ostate, p, rows, scale):
         """One party's optimizer step -> (params, opt state): the row
@@ -807,23 +848,26 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         down_res = list(tstate["down"]) if "down" in tstate else [None] * K
 
         # uplinks: every A_i's forward -> Z_i, released in wire precision
-        zs, vjps, rows_a = [], [], []
+        zs, vjps, rows_a, counts = [], [], [], []
         for i in range(K):
             pa, ba, rows, n = compact(pas[i], batches_a[i], tab_a)
-            z, vjp = jax.vjp(lambda p, ba=ba: task.forward_a(p, ba), pa)
+            z, vjp, c = jax.vjp(lambda p, ba=ba: fwd_a(p, ba), pa,
+                                has_aux=True)
             z, up_res[i] = tp.send(keys[2 * i], z, up_res[i], "up")
             zs.append(z)
             vjps.append(vjp)
             rows_a.append((rows, n))
+            counts.append(c)
 
         # Party B: loss + grads wrt (params_b, all Z_i); ∇Z_i are downlinks
         pb, bb, rows_b, n_b = compact(pb, batch_b, tab_b)
 
         def mean_loss(p, z_list):
-            li, aux = task.loss_b(p, z_list, bb)
-            return jnp.mean(li) + aux
-        loss, (g_b, dzs) = jax.value_and_grad(
-            mean_loss, argnums=(0, 1))(pb, zs)
+            li, aux, c = loss_b(p, z_list, bb)
+            return jnp.mean(li) + aux, c
+        (loss, c), (g_b, dzs) = jax.value_and_grad(
+            mean_loss, argnums=(0, 1), has_aux=True)(pb, zs)
+        counts.append(c)
         dzs = list(dzs)
         for i in range(K):
             dzs[i], down_res[i] = tp.send(keys[2 * i + 1], dzs[i],
@@ -841,6 +885,8 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         if row_path:
             fresh["rows"] = {"a": [r for r, _ in rows_a], "b": rows_b,
                              "n": _count([n for _, n in rows_a] + [n_b])}
+        if counted:
+            fresh["counts"] = _add_counts(*counts)
         return fresh
 
     @jax.named_scope(EXCHANGE_APPLY)
@@ -896,7 +942,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
             "comm_rounds": state["comm_rounds"] + 1,
             "transport": fresh["tstate"],
         }
-        m = {"loss": fresh["loss"]}
+        m = {"loss": fresh["loss"], **fresh.get("counts", {})}
         if "rows" in fresh:
             m["rows_updated"] = fresh["rows"]["n"]
         return new_state, m
@@ -940,7 +986,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                 pas, oas, wsas, nas, pb, ob, wsb, nb = carry
                 draw_key = None
             pas, oas, wsas, nas = list(pas), list(oas), list(wsas), list(nas)
-            w_means, w_zeros, counts = [], [], []
+            w_means, w_zeros, counts, passes = [], [], [], []
             for i in range(K):
                 with jax.named_scope(WORKSET_DRAW):
                     ki = None if draw_key is None \
@@ -956,7 +1002,9 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                         task.forward_a, pas[i], wsas[i], slot, cos_xi,
                         weighting=celu.weighting, fused=fused,
                         cache_fused=celu.cache_fused, mask=vf,
-                        pipeline_staleness=s_loc, tables=tab_a)
+                        pipeline_staleness=s_loc, tables=tab_a,
+                        counted=counted)
+                    passes.append(rows.pop() if counted else None)
                     rows, n = rows[0] if rows else (None, None)
                 with jax.named_scope(OPTIMIZER):
                     uf = vf if damp is None else vf * damp
@@ -982,7 +1030,9 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                     task.loss_b, pb, wsb, slot_b, cos_xi,
                     weighting=celu.weighting, fused=fused,
                     cache_fused=celu.cache_fused, mask=vf,
-                    pipeline_staleness=s_loc, tables=tab_b)
+                    pipeline_staleness=s_loc, tables=tab_b,
+                    counted=counted)
+                passes.append(rows.pop() if counted else None)
                 rows, n = rows[0] if rows else (None, None)
             with jax.named_scope(OPTIMIZER):
                 uf = vf if damp is None else vf * damp
@@ -997,6 +1047,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
                   "w_zero_frac": sum(w_zeros) * scale}
             if row_path:
                 lm["rows_updated"] = _count(counts)
+            lm.update(_add_counts(*passes))
             carry = (pas, oas, wsas, nas, pb, ob, wsb, nb)
             if uniform:
                 carry = carry + (j + 1,)
@@ -1022,8 +1073,7 @@ def _make_stages(task: KPartyTask, opt: Optimizer, celu: CELUConfig, *,
         out = {"local_steps": sum(nas) + nb,
                "w_mean": jnp.mean(lm["w_mean"]),
                "w_zero_frac": jnp.mean(lm["w_zero_frac"])}
-        if row_path:
-            out["rows_updated"] = jnp.sum(lm["rows_updated"])
+        out.update({k: jnp.sum(lm[k]) for k in COUNTERS if k in lm})
         return state, out
 
     return exchange_compute, exchange_apply, local_scan
@@ -1036,11 +1086,12 @@ def _count(ns):
 
 def merge_metrics(m, lm):
     """A round's metrics from its exchange's (``m``) and its local
-    scan's (``lm``); on the row path their ``rows_updated`` add up."""
+    scan's (``lm``); their ``COUNTERS`` add up."""
     out = {**m, **lm}
-    if "rows_updated" in m and "rows_updated" in lm:
-        with jax.named_scope(LOCAL_SCAN), jax.named_scope(OPTIMIZER):
-            out["rows_updated"] = m["rows_updated"] + lm["rows_updated"]
+    for k in COUNTERS:
+        if k in m and k in lm:
+            with jax.named_scope(LOCAL_SCAN), jax.named_scope(OPTIMIZER):
+                out[k] = m[k] + lm[k]
     return out
 
 
@@ -1138,25 +1189,30 @@ class RoundState(NamedTuple):
                 "transport": self.transport}
 
 
-def _zero_local_metrics(rows: bool = False):
+def fresh_counters(fresh) -> Tuple[str, ...]:
+    """The ``COUNTERS`` an exchange payload's merge reports."""
+    return (("rows_updated",) if "rows" in fresh else ()) \
+        + tuple(fresh.get("counts", {}))
+
+
+def _zero_local_metrics(counters: Sequence[str] = ()):
     zero = jnp.float32(0.0)
     out = {"local_steps": jnp.int32(0), "w_mean": zero, "w_zero_frac": zero}
-    if rows:
-        out["rows_updated"] = jnp.int32(0)
+    out.update({k: jnp.int32(0) for k in counters})
     return out
 
 
 def _flush_metrics(scans, merged=()):
     """A drain's metrics: its local scans' steps summed and weight
-    statistics averaged; on the row path ``rows_updated`` sums the scans'
-    and the ``merged`` exchanges' rows."""
+    statistics averaged; its ``COUNTERS`` sum the scans' and the
+    ``merged`` exchanges' counts."""
     n = len(scans)
     out = {"local_steps": sum(s["local_steps"] for s in scans),
            "w_mean": sum(s["w_mean"] for s in scans) / n,
            "w_zero_frac": sum(s["w_zero_frac"] for s in scans) / n}
-    if "rows_updated" in scans[0]:
-        out["rows_updated"] = sum(s["rows_updated"]
-                                  for s in list(scans) + list(merged))
+    for k in COUNTERS:
+        if k in scans[0]:
+            out[k] = sum(s[k] for s in list(scans) + list(merged))
     return out
 
 

@@ -49,11 +49,13 @@ from .rows import RowTables
 class VFLTask(NamedTuple):
     """Two-party split model interface (see module docstring);
     ``row_tables`` declares the parties' embedding tables
-    (``core.rows``)."""
+    (``core.rows``); ``counted``: the functions also return counters
+    (``engine.KPartyTask``)."""
     forward_a: Callable[[Any, Dict[str, Any]], jnp.ndarray]
     loss_b: Callable[[Any, jnp.ndarray, Dict[str, Any]],
                      Tuple[jnp.ndarray, jnp.ndarray]]
     row_tables: Optional[RowTables] = None
+    counted: bool = False
 
 
 # --------------------------------------------------------------------------

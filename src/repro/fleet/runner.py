@@ -190,6 +190,8 @@ def run_fleet(configs: Sequence[JobSpec], rounds: int, *,
             if z_shapes is None:
                 z_shapes = [jax.eval_shape(workload.task.forward_a, p, b)
                             for p, b in zip(params["a"], ex_ba)]
+                if workload.task.counted:
+                    z_shapes = [z for z, _ in z_shapes]
             opt = make_optimizer(spec.optimizer, spec.lr)
             state = engine.init_state(workload.task, params, opt, celu,
                                       ex_ba, ex_bb, transport=tp)

@@ -39,9 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import CELUConfig, validate_pipeline_depth
-from ..core.engine import (KPartyTask, PendingExchange, _make_stages,
-                           _zero_local_metrics, make_transport,
-                           merge_metrics)
+from ..core.engine import (COUNTERS, KPartyTask, PendingExchange,
+                           _make_stages, _zero_local_metrics,
+                           fresh_counters, make_transport, merge_metrics)
 from ..core.weighting import xi_to_cos
 from ..optim import make_optimizer
 
@@ -123,7 +123,7 @@ def average_flush_metrics(m: Dict[str, Any]) -> Dict[str, Any]:
     if "w_mean_scans" not in m:
         return dict(m)
     n = np.float32(np.asarray(m["n_scans"]))
-    out = {k: np.asarray(m[k]) for k in ("local_steps", "rows_updated")
+    out = {k: np.asarray(m[k]) for k in ("local_steps",) + COUNTERS
            if k in m}
     for key in ("w_mean", "w_zero_frac"):
         acc = np.float32(0.0)
@@ -247,8 +247,8 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
         def _warmup(args):
             state, pending, n = args
             m = {"loss": jnp.float32(jnp.nan)}
-            if "rows" in fresh:         # the row path: no step ran
-                m["rows_updated"] = jnp.int32(0)
+            # no step ran: every counter reads 0
+            m.update({k: jnp.int32(0) for k in fresh_counters(fresh)})
             return state, pending, n, m
 
         state, pending, n, m = jax.lax.cond(
@@ -273,7 +273,7 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
         # which breaks bit-parity with PipelinedEngine.flush's eager
         # per-op adds.
         n0 = fs.n_pending
-        zeros = _zero_local_metrics(rows="rows" in fs.pending.fresh)
+        zeros = _zero_local_metrics(fresh_counters(fs.pending.fresh))
 
         def _drain(args):
             state, pending, n = args
@@ -304,8 +304,9 @@ def make_fleet_step(task: KPartyTask, celu: CELUConfig, *,
                                             for r in rows]),
             "n_scans": n0 + 1,
         }
-        if "rows_updated" in lm:
-            metrics["rows_updated"] = sum(r["rows_updated"] for r in rows)
+        for k in COUNTERS:
+            if k in lm:
+                metrics[k] = sum(r[k] for r in rows)
         return FleetRoundState(state, pending, n), metrics
 
     return init, step, flush

@@ -221,8 +221,7 @@ def dryrun(arch_id: str, shape_name: str, *, multi_pod: bool = False,
            fsdp: bool = True, moe_sharding: str = "",
            donate: bool = True, extra_tag: str = "",
            microbatches: int = 1, unroll_microbatches: bool = False,
-           pure_dp: bool = False, zero1: bool = False,
-           moe_capacity: float = 0.0) -> Dict[str, Any]:
+           pure_dp: bool = False, zero1: bool = False) -> Dict[str, Any]:
     """``pure_dp``: batch over (pod, data, model) — all 256/512 chips data-
     parallel, tower weights replicated (embeddings/head still model-sharded
     via the name rules' divisibility checks being moot doesn't apply — in
@@ -231,10 +230,6 @@ def dryrun(arch_id: str, shape_name: str, *, multi_pod: bool = False,
     cfg = arch_for_shape(arch_id, shape_name)
     if not moe_sharding:   # default: the arch config's choice (§Perf 2.4)
         moe_sharding = cfg.moe.sharding if cfg.moe is not None else "tp"
-    if moe_capacity and cfg.moe is not None:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-            cfg.moe, capacity_factor=moe_capacity))
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = int(np.prod(list(mesh.shape.values())))
@@ -409,7 +404,6 @@ def main(argv=None) -> int:
     ap.add_argument("--unroll-microbatch", action="store_true")
     ap.add_argument("--pure-dp", action="store_true")
     ap.add_argument("--zero1", action="store_true")
-    ap.add_argument("--moe-capacity", type=float, default=0.0)
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=None, help="append JSONL here")
     args = ap.parse_args(argv)
@@ -431,8 +425,7 @@ def main(argv=None) -> int:
                          moe_sharding=args.moe_sharding, extra_tag=args.tag,
                          microbatches=args.microbatch,
                          unroll_microbatches=args.unroll_microbatch,
-                         pure_dp=args.pure_dp, zero1=args.zero1,
-                         moe_capacity=args.moe_capacity)
+                         pure_dp=args.pure_dp, zero1=args.zero1)
         except Exception as e:  # noqa: BLE001 — record failures, keep going
             res = {"arch": a, "shape": s,
                    "mesh": "2x16x16" if mp else "16x16", "ok": False,

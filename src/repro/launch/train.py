@@ -89,15 +89,21 @@ def _ckpt_extra_ref(n_pending: int, chaos: bool):
 def llm_task(cfg: ArchConfig, remat: bool = True) -> proto.VFLTask:
     """VFLTask over the LLM backbone split (text archs).  ``remat``
     toggles activation checkpointing of the tower scans (models.backbone
-    Ctx.remat)."""
+    Ctx.remat).  A model with experts counts the (token, slot) rows its
+    held experts compute (``expert_rows`` in the round's metrics)."""
+    counted = cfg.moe is not None
+
     def forward_a(pa, batch_a):
-        return vfl.forward_a(pa, cfg, batch_a, train=True, remat=remat)
+        out = vfl.forward_a(pa, cfg, batch_a, train=True, remat=remat,
+                            counts=counted)
+        return (out[0], {"expert_rows": out[1]}) if counted else out
 
     def loss_b(pb, z_a, batch_b):
-        return vfl.per_instance_loss(pb, cfg, z_a, batch_b, train=True,
-                                     remat=remat)
+        out = vfl.per_instance_loss(pb, cfg, z_a, batch_b, train=True,
+                                    remat=remat, counts=counted)
+        return out[:2] + ({"expert_rows": out[2]},) if counted else out
 
-    return proto.VFLTask(forward_a, loss_b)
+    return proto.VFLTask(forward_a, loss_b, counted=counted)
 
 
 def make_opt(args):

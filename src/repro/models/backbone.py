@@ -10,6 +10,8 @@ alternation, the VLM's every-5th cross-attention) compile as scans too.
 Block types:
   dense   : RMSNorm -> GQA attn -> RMSNorm -> gated MLP     (llama family)
   moe     : RMSNorm -> GQA attn -> RMSNorm -> MoE FFN
+  sconv   : RMSNorm -> gated short conv -> RMSNorm -> gated MLP   (lfm2)
+  sconv_moe : RMSNorm -> gated short conv -> RMSNorm -> MoE FFN   (lfm2)
   hybrid  : RMSNorm -> (attn ∥ mamba)/2 -> RMSNorm -> MLP   (hymba)
   mlstm   : RMSNorm -> mLSTM cell                            (xlstm)
   slstm   : RMSNorm -> sLSTM cell                            (xlstm)
@@ -38,11 +40,35 @@ from . import xlstm as X
 # --------------------------------------------------------------------------
 # Tower stage layouts
 # --------------------------------------------------------------------------
-def tower_stages(cfg: ArchConfig, n_layers: int, role: str
+def layer_block(cfg: ArchConfig, i: int) -> str:
+    """The block type of published layer ``i`` of a per-layer-kind config
+    (``cfg.layer_types``): its mixer, and a dense FFN for the first
+    ``n_dense_layers`` layers, the MoE after them."""
+    moe = cfg.moe is not None and i >= cfg.n_dense_layers
+    if cfg.layer_types[i] == "conv":
+        return "sconv_moe" if moe else "sconv"
+    if cfg.layer_types[i] == "full_attention":
+        return "moe" if moe else "dense"
+    raise ValueError(f"layer type {cfg.layer_types[i]!r}")
+
+
+def tower_stages(cfg: ArchConfig, n_layers: int, role: str, first: int = 0
                  ) -> Sequence[Tuple[Tuple[str, ...], int]]:
-    """role: text | vlm | enc | audio_dec."""
+    """role: text | vlm | enc | audio_dec.  ``first``: the published index
+    of the tower's first layer, for configs with per-layer kinds, whose
+    towers are slices of the published layer sequence (a stage per run of
+    one block type)."""
     if n_layers <= 0:
         return []
+    if cfg.layer_types and role == "text":
+        stages = []
+        for i in range(first, first + n_layers):
+            bt = layer_block(cfg, i)
+            if stages and stages[-1][0] == (bt,):
+                stages[-1] = ((bt,), stages[-1][1] + 1)
+            else:
+                stages.append(((bt,), 1))
+        return stages
     if role == "enc":
         return [(("enc",), n_layers)]
     if role == "audio_dec":
@@ -81,12 +107,19 @@ def block_init(rng, cfg: ArchConfig, btype: str):
     if btype in ("dense", "moe", "enc"):
         p = {"ln1": ln(), "ln2": ln(),
              "attn": L.attention_init(ks[0], d, cfg.n_heads, cfg.n_kv_heads,
-                                      hd, qkv_bias=cfg.qkv_bias)}
+                                      hd, qkv_bias=cfg.qkv_bias,
+                                      qk_norm=cfg.qk_norm)}
         if btype == "moe":
             p["ffn"] = M.moe_init(ks[1], d, cfg.d_ff, cfg.moe)
         else:
             p["ffn"] = L.mlp_init(ks[1], d, cfg.d_ff)
         return p
+    if btype in ("sconv", "sconv_moe"):
+        return {"ln1": ln(), "ln2": ln(),
+                "conv": S.short_conv_init(ks[0], d),
+                "ffn": (M.moe_init(ks[1], d, cfg.d_ff, cfg.moe)
+                        if btype == "sconv_moe"
+                        else L.mlp_init(ks[1], d, cfg.d_ff))}
     if btype == "hybrid":
         return {"ln1": ln(), "lnm": ln(), "ln2": ln(),
                 "attn": L.attention_init(ks[0], d, cfg.n_heads,
@@ -126,13 +159,14 @@ class Ctx:
 
 
 def _ffn(params, x, cfg, btype):
-    if btype == "moe":
+    """-> (y, aux loss, expert rows computed)."""
+    if btype in ("moe", "sconv_moe"):
         return M.moe_apply(params["ffn"], x, cfg.moe)
-    return L.mlp_apply(params["ffn"], x), 0.0
+    return L.mlp_apply(params["ffn"], x), 0.0, 0
 
 
 def block_apply_full(params, x, btype: str, ctx: Ctx):
-    """Full-sequence forward.  Returns (x, aux_loss)."""
+    """Full-sequence forward.  Returns (x, aux_loss, expert_rows)."""
     cfg = ctx.cfg
     eps = cfg.norm_eps
     if btype in ("mlstm", "slstm"):
@@ -140,10 +174,16 @@ def block_apply_full(params, x, btype: str, ctx: Ctx):
         # see xlstm.mlstm_apply_chunked); sLSTM is inherently sequential.
         cell = X.mlstm_apply_chunked if btype == "mlstm" else X.slstm_apply
         h, _ = cell(params["cell"], L.rmsnorm(params["ln1"], x, eps))
-        return x + h, 0.0
+        return x + h, 0.0, 0
+    if btype in ("sconv", "sconv_moe"):
+        x = x + S.short_conv_apply(params["conv"],
+                                   L.rmsnorm(params["ln1"], x, eps))
+        y, aux, rows = _ffn(params, L.rmsnorm(params["ln2"], x, eps), cfg,
+                            btype)
+        return x + y, aux, rows
     attn_kw = dict(positions=ctx.positions, theta=cfg.rope_theta,
                    causal=(ctx.causal and btype != "enc"),
-                   window=ctx.window)
+                   window=ctx.window, eps=eps)
     h = L.rmsnorm(params["ln1"], x, eps)
     a = L.attention_apply(params["attn"], h, **attn_kw)
     if btype == "hybrid":
@@ -159,8 +199,8 @@ def block_apply_full(params, x, btype: str, ctx: Ctx):
                                   memory_positions=ctx.memory_positions,
                                   use_rope=False)
     h2 = L.rmsnorm(params["ln2"], x, eps)
-    y, aux = _ffn(params, h2, cfg, btype)
-    return x + y, aux
+    y, aux, rows = _ffn(params, h2, cfg, btype)
+    return x + y, aux, rows
 
 
 # ---- caches ---------------------------------------------------------------
@@ -178,6 +218,9 @@ def block_make_cache(cfg: ArchConfig, btype: str, batch: int, capacity: int,
                                         PARAM_DTYPE),
                          "v": jnp.zeros((batch, memory_len, kv, hd),
                                         PARAM_DTYPE)}}
+    if btype in ("sconv", "sconv_moe"):
+        return {"conv": jnp.zeros((batch, S.SHORT_CONV_KERNEL - 1, d),
+                                  PARAM_DTYPE)}
     if btype == "mlstm":
         return {"state": X.make_mlstm_state(batch, cfg.n_heads, d // cfg.n_heads)}
     if btype == "slstm":
@@ -194,9 +237,18 @@ def block_decode(params, x, btype: str, ctx: Ctx, cache):
         h, st = cell(params["cell"], L.rmsnorm(params["ln1"], x, eps),
                      cache["state"])
         return x + h, 0.0, {"state": st}
+    if btype in ("sconv", "sconv_moe"):
+        h, st = S.short_conv_decode(params["conv"],
+                                    L.rmsnorm(params["ln1"], x, eps),
+                                    cache["conv"])
+        x = x + h
+        y, aux, _ = _ffn(params, L.rmsnorm(params["ln2"], x, eps), cfg,
+                         btype)
+        return x + y, aux, {"conv": st}
     h = L.rmsnorm(params["ln1"], x, eps)
     a, kv = L.attention_decode(params["attn"], h, cache["attn"], ctx.pos,
-                               theta=cfg.rope_theta, window=ctx.window)
+                               theta=cfg.rope_theta, window=ctx.window,
+                               eps=eps)
     new_cache = dict(cache)
     new_cache["attn"] = kv
     if btype == "hybrid":
@@ -211,20 +263,23 @@ def block_decode(params, x, btype: str, ctx: Ctx, cache):
         hx = L.rmsnorm(params["lnx"], x, eps)
         x = x + L.cross_attention_decode(params["xattn"], hx, cache["xmem"])
     h2 = L.rmsnorm(params["ln2"], x, eps)
-    y, aux = _ffn(params, h2, cfg, btype)
+    y, aux, _ = _ffn(params, h2, cfg, btype)
     return x + y, aux, new_cache
 
 
 def block_prefill(params, x, btype: str, ctx: Ctx, capacity: int):
     """Full-sequence forward that also emits the decode cache."""
     cfg = ctx.cfg
-    y, aux = block_apply_full(params, x, btype, ctx)
+    y, aux, _ = block_apply_full(params, x, btype, ctx)
     B, Sq = x.shape[0], x.shape[1]
     if btype in ("mlstm", "slstm"):
         cell = X.mlstm_apply_chunked if btype == "mlstm" else X.slstm_apply
         _, st = cell(params["cell"],
                      L.rmsnorm(params["ln1"], x, cfg.norm_eps))
         return y, aux, {"state": st}
+    if btype in ("sconv", "sconv_moe"):
+        return y, aux, {"conv": S.short_conv_state(
+            params["conv"], L.rmsnorm(params["ln1"], x, cfg.norm_eps))}
     # KV cache from the (normed) block input — recompute K/V projections
     h = L.rmsnorm(params["ln1"], x, cfg.norm_eps)
     k = jnp.einsum("bsd,dhk->bshk", h, params["attn"]["wk"])
@@ -232,6 +287,8 @@ def block_prefill(params, x, btype: str, ctx: Ctx, capacity: int):
     if "bk" in params["attn"]:
         k = k + params["attn"]["bk"]
         v = v + params["attn"]["bv"]
+    if "k_norm" in params["attn"]:
+        k = L.rmsnorm(params["attn"]["k_norm"], k, cfg.norm_eps)
     k = L.rope(k, ctx.positions, cfg.rope_theta)
     cap = capacity
     tail = min(cap, Sq)
@@ -286,21 +343,24 @@ def tower_make_cache(cfg: ArchConfig, stages, batch: int, capacity: int,
     return caches
 
 
-def tower_apply(params, x, cfg: ArchConfig, stages, ctx: Ctx):
-    """Train/eval full-sequence forward.  Returns (x, aux)."""
-    aux = jnp.float32(0.0)
+def tower_apply(params, x, cfg: ArchConfig, stages, ctx: Ctx,
+                counts: bool = False):
+    """Train/eval full-sequence forward.  Returns (x, aux), and with
+    ``counts`` (x, aux, expert rows the tower's MoE layers computed)."""
+    carry = (x, jnp.float32(0.0)) + ((jnp.int32(0),) if counts else ())
     for sp, (pattern, repeat) in zip(params, stages):
         def body(carry, p_layer, _pattern=pattern):
-            h, a = carry
+            h, a, *r = carry
             h = L.shard_batch_dim(h)   # pin batch sharding in the loop body
             for i, bt in enumerate(_pattern):
-                h, ai = block_apply_full(p_layer[f"b{i}"], h, bt, ctx)
+                h, ai, ri = block_apply_full(p_layer[f"b{i}"], h, bt, ctx)
                 a = a + ai
-            return (L.shard_batch_dim(h), a), None
+                r = [r[0] + ri] if r else r
+            return (L.shard_batch_dim(h), a, *r), None
         if ctx.train and ctx.remat:
             body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux), sp)
-    return x, aux
+        carry, _ = jax.lax.scan(body, carry, sp)
+    return carry
 
 
 def tower_prefill(params, x, cfg: ArchConfig, stages, ctx: Ctx,

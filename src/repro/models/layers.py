@@ -122,7 +122,7 @@ class AttnParams(NamedTuple):
 
 def attention_init(rng, d_model: int, n_heads: int, n_kv: int, head_dim: int,
                    *, kv_input_dim: Optional[int] = None,
-                   qkv_bias: bool = False):
+                   qkv_bias: bool = False, qk_norm: bool = False):
     kd = kv_input_dim or d_model
     ks = jax.random.split(rng, 4)
     p = {
@@ -137,7 +137,18 @@ def attention_init(rng, d_model: int, n_heads: int, n_kv: int, head_dim: int,
         p["bq"] = zeros_init((n_heads, head_dim))
         p["bk"] = zeros_init((n_kv, head_dim))
         p["bv"] = zeros_init((n_kv, head_dim))
+    if qk_norm:
+        p["q_norm"] = rmsnorm_init(head_dim)
+        p["k_norm"] = rmsnorm_init(head_dim)
     return p
+
+
+def qk_normed(params, q, k, eps: float):
+    """Per-head RMSNorm of q and k (``q_norm``/``k_norm``), where the
+    attention has it; applied before RoPE."""
+    if "q_norm" not in params:
+        return q, k
+    return rmsnorm(params["q_norm"], q, eps), rmsnorm(params["k_norm"], k, eps)
 
 
 def _project_qkv(params, x, kv_src):
@@ -236,12 +247,13 @@ def _blockwise_sdpa(q, k, v, q_pos, k_pos, *, causal: bool, window: int):
 def attention_apply(params, x, *, positions, theta: float = 10000.0,
                     causal: bool = True, window: int = 0,
                     memory=None, memory_positions=None,
-                    use_rope: bool = True):
+                    use_rope: bool = True, eps: float = 1e-5):
     """Full-sequence attention.  If ``memory`` is given -> cross-attention
     (no mask, no rope on memory unless memory_positions given)."""
     n_heads = params["wq"].shape[1]
     kv_src = memory if memory is not None else x
     q, k, v = _project_qkv(params, x, kv_src)
+    q, k = qk_normed(params, q, k, eps)
     S = x.shape[1]
     if use_rope:
         q = rope(q, positions, theta)
@@ -280,7 +292,8 @@ def make_kv_cache(batch: int, capacity: int, n_kv: int, head_dim: int,
 
 
 def attention_decode(params, x, cache, pos, *, theta: float = 10000.0,
-                     window: int = 0, use_rope: bool = True):
+                     window: int = 0, use_rope: bool = True,
+                     eps: float = 1e-5):
     """One-token decode.  x: (B, 1, d); pos: scalar int32 absolute position.
 
     The cache is a ring buffer of ``capacity`` slots (capacity == window for
@@ -288,6 +301,7 @@ def attention_decode(params, x, cache, pos, *, theta: float = 10000.0,
     """
     n_heads = params["wq"].shape[1]
     q, k_new, v_new = _project_qkv(params, x, x)
+    q, k_new = qk_normed(params, q, k_new, eps)
     pos_arr = jnp.reshape(pos, (1,))
     if use_rope:
         q = rope(q, pos_arr, theta)

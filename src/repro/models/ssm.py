@@ -147,3 +147,57 @@ def mamba_decode(params, x, cache, cfg: SSMConfig):
         z.astype(jnp.float32)).astype(x.dtype) * z
     out = jnp.einsum("bsd,de->bse", y, params["out_proj"])
     return out, {"h": h, "conv": window[:, 1:]}
+
+
+# --------------------------------------------------------------------------
+# Gated short convolution (lfm2's conv mixer)
+# --------------------------------------------------------------------------
+SHORT_CONV_SCOPE = "short_conv"
+SHORT_CONV_KERNEL = 3            # lfm2's taps (``conv_L_cache``)
+
+
+def short_conv_init(rng, d_model: int, kernel: int = SHORT_CONV_KERNEL):
+    """``in_proj`` d x 3d (B, C, x in that order), ``conv_w`` (K, d) with
+    ``conv_w[j]`` the weight of position t - j (``_causal_conv``'s layout),
+    ``out_proj`` d x d; no biases."""
+    ks = jax.random.split(rng, 3)
+    lim = 1.0 / jnp.sqrt(jnp.float32(kernel))
+    return {"in_proj": dense_init(ks[0], d_model, 3 * d_model),
+            "conv_w": jax.random.uniform(ks[1], (kernel, d_model),
+                                         jnp.float32, -lim, lim
+                                         ).astype(jnp.bfloat16),
+            "out_proj": dense_init(ks[2], d_model, d_model)}
+
+
+def _short_conv_gates(params, x):
+    bcx = jnp.einsum("bsd,de->bse", x, params["in_proj"])
+    b, c, xs = jnp.split(bcx, 3, axis=-1)
+    return b * xs, c
+
+
+def short_conv_apply(params, x):
+    """y = C * causal_depthwise_conv(B * x) @ W_out over a full sequence."""
+    with jax.named_scope(SHORT_CONV_SCOPE):
+        bx, c = _short_conv_gates(params, x)
+        y = c * _causal_conv(bx, params["conv_w"])
+        return jnp.einsum("bsd,de->bse", y, params["out_proj"])
+
+
+def short_conv_state(params, x):
+    """The decode state after a full sequence: its last K-1 values of
+    B * x, zeros before the first position."""
+    bx, _ = _short_conv_gates(params, x)
+    K = params["conv_w"].shape[0]
+    bx = jnp.pad(bx, ((0, 0), (K - 1, 0), (0, 0)))
+    return bx[:, bx.shape[1] - (K - 1):]
+
+
+def short_conv_decode(params, x, state):
+    """One token.  x: (B, 1, d); state: (B, K-1, d) -> (y, state)."""
+    with jax.named_scope(SHORT_CONV_SCOPE):
+        bx, c = _short_conv_gates(params, x)
+        window = jnp.concatenate([state, bx.astype(state.dtype)], axis=1)
+        conv = jnp.einsum("bkd,kd->bd", window[:, ::-1].astype(jnp.float32),
+                          params["conv_w"].astype(jnp.float32))
+        y = c * conv[:, None].astype(x.dtype)
+        return jnp.einsum("bsd,de->bse", y, params["out_proj"]), window[:, 1:]

@@ -41,14 +41,20 @@ def stages_a(cfg: ArchConfig):
     return tower_stages(cfg, cfg.vfl_split.layers_a, "text")
 
 
+# With per-layer kinds, each tower is its slice of the published layer
+# sequence: A's tower the first ``layers_a`` layers, B's bottom the next
+# ``layers_b``, its top the ``layers_top`` after them.
 def stages_b(cfg: ArchConfig):
     role = _role(cfg)
-    return tower_stages(cfg, cfg.vfl_split.layers_b, role)
+    return tower_stages(cfg, cfg.vfl_split.layers_b, role,
+                        first=cfg.vfl_split.layers_a)
 
 
 def stages_top(cfg: ArchConfig):
     role = _role(cfg)
-    return tower_stages(cfg, cfg.vfl_split.layers_top, role)
+    s = cfg.vfl_split
+    return tower_stages(cfg, s.layers_top, role,
+                        first=s.layers_a + s.layers_b)
 
 
 # --------------------------------------------------------------------------
@@ -89,8 +95,9 @@ def init_all(rng, cfg: ArchConfig):
 # Party A forward
 # --------------------------------------------------------------------------
 def forward_a(params_a, cfg: ArchConfig, batch: Dict[str, Any],
-              train: bool = False, remat: bool = True):
-    """-> Z_A.  text: (B,S,d); vlm: (B,P,d); audio: (B,S_a,d)."""
+              train: bool = False, remat: bool = True, counts: bool = False):
+    """-> Z_A.  text: (B,S,d); vlm: (B,P,d); audio: (B,S_a,d).  With
+    ``counts`` (text families) -> (Z_A, expert rows computed)."""
     if cfg.family == "vlm":
         h = jax.nn.silu(jnp.einsum(
             "bpf,fd->bpd", batch["patches"].astype(PARAM_DTYPE),
@@ -109,8 +116,9 @@ def forward_a(params_a, cfg: ArchConfig, batch: Dict[str, Any],
     S = x.shape[1]
     ctx = Ctx(cfg, positions=jnp.arange(S, dtype=jnp.int32), train=train,
               remat=remat, window=cfg.sliding_window)
-    x, _ = tower_apply(params_a["tower"], x, cfg, stages_a(cfg), ctx)
-    return x
+    x, _, *rows = tower_apply(params_a["tower"], x, cfg, stages_a(cfg), ctx,
+                              counts=counts)
+    return (x, rows[0]) if counts else x
 
 
 # --------------------------------------------------------------------------
@@ -128,8 +136,9 @@ def _logits(h, params_b, cfg: ArchConfig):
 
 
 def forward_b(params_b, cfg: ArchConfig, z_a, batch: Dict[str, Any],
-              train: bool = False, remat: bool = True):
-    """-> (logits, aux).  z_a enters via the fusion declared by the split."""
+              train: bool = False, remat: bool = True, counts: bool = False):
+    """-> (logits, aux), and with ``counts`` the expert rows computed
+    after them.  z_a enters via the fusion declared by the split."""
     x = params_b["embed"][batch["tokens"]]
     S = x.shape[1]
     pos = jnp.arange(S, dtype=jnp.int32)
@@ -137,18 +146,23 @@ def forward_b(params_b, cfg: ArchConfig, z_a, batch: Dict[str, Any],
     mem = z_a if fusion == "cross_attn" else None
     ctx = Ctx(cfg, positions=pos, memory=mem, train=train, remat=remat,
               window=cfg.sliding_window)
-    x, aux1 = tower_apply(params_b["bottom"], x, cfg, stages_b(cfg), ctx)
+    x, aux1, *r1 = tower_apply(params_b["bottom"], x, cfg, stages_b(cfg),
+                               ctx, counts=counts)
     if fusion == "add":
         x = x + jnp.einsum("bsd,de->bse", z_a, params_b["fuse_proj"])
-    x, aux2 = tower_apply(params_b["top"], x, cfg, stages_top(cfg), ctx)
-    return _logits(x, params_b, cfg), aux1 + aux2
+    x, aux2, *r2 = tower_apply(params_b["top"], x, cfg, stages_top(cfg), ctx,
+                               counts=counts)
+    out = (_logits(x, params_b, cfg), aux1 + aux2)
+    return out + (r1[0] + r2[0],) if counts else out
 
 
 def per_instance_loss(params_b, cfg: ArchConfig, z_a, batch,
-                      train: bool = True, remat: bool = True):
-    """Cross-entropy per instance (B,) + aux scalar — Party B's objective."""
-    logits, aux = forward_b(params_b, cfg, z_a, batch, train=train,
-                            remat=remat)
+                      train: bool = True, remat: bool = True,
+                      counts: bool = False):
+    """Cross-entropy per instance (B,) + aux scalar — Party B's objective
+    (with ``counts``, + the expert rows computed)."""
+    logits, aux, *rows = forward_b(params_b, cfg, z_a, batch, train=train,
+                                   remat=remat, counts=counts)
     labels = batch["labels"]
     # Sharding-friendly cross-entropy: logsumexp + one-hot-reduction both
     # lower to vocab-dim-local reductions + psum when the vocab is sharded
@@ -158,7 +172,7 @@ def per_instance_loss(params_b, cfg: ArchConfig, z_a, batch,
                                              dtype=labels.dtype)
     label_logit = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
     nll = lse - label_logit
-    return jnp.mean(nll, axis=-1), aux
+    return (jnp.mean(nll, axis=-1), aux) + tuple(rows)
 
 
 def joint_loss(params, cfg: ArchConfig, batch, train: bool = True):
